@@ -298,7 +298,7 @@ def nrank_channel(topo: Topology, traffic: np.ndarray,
     # fp64 evolution (scoped x64): keeps this oracle and the fused device
     # pipeline (`plan_fast`, fp64 on CPU) within summation-order noise,
     # so tie-tolerance-boundary choice flips cannot separate them.
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         wc = jnp.asarray(w0c)
         mj = jnp.asarray(m)
         aggj = jnp.asarray(agg)

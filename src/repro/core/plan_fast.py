@@ -88,7 +88,7 @@ def _precision_scope(precision: str):
     """Context manager selecting the accumulation dtype of the fast path."""
     precision = _resolve_precision(precision)
     if precision == "fp64":
-        return jax.experimental.enable_x64()
+        return jax.enable_x64(True)
     if precision != "fp32":
         raise ValueError(f"unknown precision {precision!r}")
     return contextlib.nullcontext()
@@ -125,8 +125,10 @@ class PlanStatics:
     pair_c2: jnp.ndarray     # (P,) consecutive-pair second channel
     nh: jnp.ndarray          # (O, N, N) DOR next-hop tables
     port_tables: np.ndarray  # (O, N, N) int8, host (BiDOR artifact)
-    core: object             # jitted single-plan computation
-    core_batched: object     # jitted vmapped computation
+    # the jitted plan computation, vmapped over traffic matrices; single
+    # builds run it on a batch of one, so they match batched lanes bit
+    # for bit
+    core_batched: object
     jvals: object = None     # jitted joint-possibility values (lazy)
 
 
@@ -355,7 +357,6 @@ def plan_statics(topo: Topology, *, binary_only: bool = True,
         us=arrays["us"], ns=arrays["ns"],
         pair_c1=arrays["pair_c1"], pair_c2=arrays["pair_c2"],
         nh=arrays["nh"], port_tables=ports,
-        core=jax.jit(core),
         core_batched=jax.jit(jax.vmap(
             core, in_axes=(None, 0, 0, 0, None, None, None, None))),
     )
@@ -525,11 +526,13 @@ def build_plan_fast(topo: Topology, traffic: np.ndarray, *,
         t = jnp.asarray(np.asarray(traffic, np.float64))
         w0_eff = jnp.asarray(np.asarray(
             initial_weights(traffic) if w0 is None else w0, np.float64))
-        out = statics.core(jnp.asarray(dist), t, w0_eff,
-                           jnp.asarray(w0 is not None),
-                           jnp.asarray(live), jnp.asarray(down_pair),
-                           jnp.asarray(float(w_th)), jnp.int32(iter_th))
-        out = jax.device_get(out)
+        out = statics.core_batched(jnp.asarray(dist), t[None],
+                                   w0_eff[None],
+                                   jnp.asarray([w0 is not None]),
+                                   jnp.asarray(live), jnp.asarray(down_pair),
+                                   jnp.asarray(float(w_th)),
+                                   jnp.int32(iter_th))
+        out = {k: v[0] for k, v in jax.device_get(out).items()}
     plan = _assemble_plan(topo, traffic, statics, out, bool(down.size))
     plan = gate_plan(topo, plan, tracer=tracer, label="build_plan_fast")
     t_end = tracer.now_us()

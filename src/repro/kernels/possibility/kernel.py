@@ -1,22 +1,23 @@
 """Pallas TPU kernels: N-Rank possibility weights (the O(C·N²) hot spot).
 
-Two variants share one blocking scheme — grid (channel blocks, source
-blocks), destinations reduced inside the kernel:
+One kernel computes the per-destination possibility traffic
 
-* ``possibility_weights_pallas`` — the classic (W, W_drn) reduction
-  (eq. 5/7), accumulated per channel block.
-* ``possibility_v_pallas`` — the per-destination possibility traffic
-  ``V[c, d]`` consumed by the fused planning pipeline
-  (:mod:`repro.core.plan_fast`): W is its row sum, W_drn its ``d = n``
-  gather, and the consecutive-channel joint possibility a cheap O(P·N)
-  contraction of it.
+    V[c, d] = Σ_s T[s,d]·[du[s,c] + offset + dn[c,d] == dist[s,d]]
 
-The accumulator lives in the output block (revisited across the
-s-dimension of the grid — Pallas keeps the block in VMEM between visits
-because the index_map ignores the s axis).  All tiles are (128-multiple)
-MXU/VPU-aligned; compares and multiply-reduces are VPU work, so the
-kernels are HBM-bandwidth-bound — tiling T once per (c, s) block instead
-of the naive C passes over T is the win over the jnp oracle.
+consumed by the fused planning pipeline (:mod:`repro.core.plan_fast`):
+W is its row sum, W_drn its ``d = n`` gather, and the consecutive-channel
+joint possibility a cheap O(P·N) contraction of it.
+``possibility_weights_pallas`` — the classic (W, W_drn) reduction of
+eq. 5/7 — is that row sum plus the O(N·C) draining term.
+
+Blocking: grid (channel blocks, destination blocks, source blocks), with
+the sources reduced into the output block, which stays in VMEM across the
+source axis because its index map ignores it.  Per grid step the
+(BC, BS, BD) mask is a broadcast compare followed by a multiply with T
+and a sum over ``s`` — plain VPU work that Mosaic lowers, with no batched
+contraction.  The destination axis keeps the mask at 2 MiB whatever the
+network size; without it the (BC, BS, N) mask of a 64×64 mesh needs
+256 MiB.
 
 ``offset`` generalizes the minimal-path predicate to k-hop continuations
 (``offset=1`` is eq. 4/5; ``offset=2`` the consecutive-pair predicate).
@@ -33,102 +34,70 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _kernel(du_ref, dn_ref, dsn_ref, tn_ref, t_ref, dist_ref,
-            w_ref, wdrn_ref, *, offset: int):
-    sb = pl.program_id(1)
-    du = du_ref[...]           # (BS, BC)
-    dn = dn_ref[...]           # (BC, N)
-    dist = dist_ref[...]       # (BS, N)
-    t = t_ref[...]             # (BS, N)
-    lhs = du.T[:, :, None] + offset + dn[:, None, :]     # (BC, BS, N)
-    mask = (lhs == dist[None]).astype(t.dtype)
-    w_part = jnp.einsum("csd,sd->c", mask, t)       # (BC,)
-    drn = ((du + offset) == dsn_ref[...]).astype(t.dtype)
-    wdrn_part = jnp.sum(drn * tn_ref[...], axis=0)  # (BC,)
-
-    @pl.when(sb == 0)
-    def _init():
-        w_ref[...] = jnp.zeros_like(w_ref)
-        wdrn_ref[...] = jnp.zeros_like(wdrn_ref)
-
-    w_ref[...] += w_part
-    wdrn_ref[...] += wdrn_part
-
-
-@functools.partial(jax.jit, static_argnames=("block_c", "block_s",
-                                             "offset", "interpret"))
-def possibility_weights_pallas(du, dn, dsn, tn, traffic, dist,
-                               block_c: int = 128, block_s: int = 128,
-                               offset: int = 1,
-                               interpret: bool = False):
-    n, c = du.shape
-    bc = min(block_c, c)
-    bs = min(block_s, n)
-    grid = (-(-c // bc), -(-n // bs))
-    w, wdrn = pl.pallas_call(
-        functools.partial(_kernel, offset=offset),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bs, bc), lambda cb, sb: (sb, cb)),   # du
-            pl.BlockSpec((bc, n), lambda cb, sb: (cb, 0)),     # dn
-            pl.BlockSpec((bs, bc), lambda cb, sb: (sb, cb)),   # dsn
-            pl.BlockSpec((bs, bc), lambda cb, sb: (sb, cb)),   # tn
-            pl.BlockSpec((bs, n), lambda cb, sb: (sb, 0)),     # traffic
-            pl.BlockSpec((bs, n), lambda cb, sb: (sb, 0)),     # dist
-        ],
-        out_specs=[
-            pl.BlockSpec((bc,), lambda cb, sb: (cb,)),
-            pl.BlockSpec((bc,), lambda cb, sb: (cb,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((c,), traffic.dtype),
-            jax.ShapeDtypeStruct((c,), traffic.dtype),
-        ],
-        interpret=interpret,
-    )(du, dn, dsn, tn, traffic, dist)
-    return w, wdrn
+# Destinations per grid step: one lane-width of the (BC, BD) output block.
+_BLOCK_D = 128
 
 
 def _v_kernel(du_ref, dn_ref, t_ref, dist_ref, v_ref, *, offset: int):
-    sb = pl.program_id(1)
-    du = du_ref[...]           # (BS, BC)
-    dn = dn_ref[...]           # (BC, N)
-    dist = dist_ref[...]       # (BS, N)
-    t = t_ref[...]             # (BS, N)
-    lhs = du.T[:, :, None] + offset + dn[:, None, :]     # (BC, BS, N)
-    mask = (lhs == dist[None]).astype(t.dtype)
-    v_part = jnp.einsum("csd,sd->cd", mask, t)      # (BC, N)
-
-    @pl.when(sb == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         v_ref[...] = jnp.zeros_like(v_ref)
 
-    v_ref[...] += v_part
+    du = du_ref[...]                                   # (BS, BC)
+    lhs = du.T[:, :, None] + offset + dn_ref[...][:, None, :]  # (BC,BS,BD)
+    mask = (lhs == dist_ref[...][None]).astype(t_ref.dtype)
+    v_ref[...] += jnp.sum(mask * t_ref[...][None], axis=1)
+
+
+def _pad_to(x, rows: int, cols: int):
+    pr, pc = (-x.shape[0]) % rows, (-x.shape[1]) % cols
+    return jnp.pad(x, ((0, pr), (0, pc))) if pr or pc else x
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_s",
                                              "offset", "interpret"))
 def possibility_v_pallas(du, dn, traffic, dist,
-                         block_c: int = 128, block_s: int = 128,
-                         offset: int = 1,
-                         interpret: bool = False):
+                         block_c: int = 128, block_s: int = 32,
+                         offset: int = 1, interpret: bool = False):
     """Per-destination possibility traffic V (C, N):
-    ``V[c, d] = Σ_s T[s,d]·[du[s,c] + offset + dn[c,d] == dist[s,d]]``."""
+    ``V[c, d] = Σ_s T[s,d]·[du[s,c] + offset + dn[c,d] == dist[s,d]]``.
+
+    Operands are zero-padded up to whole blocks: padded sources carry
+    zero traffic, and padded channels and destinations are sliced off.
+    """
     n, c = du.shape
-    bc = min(block_c, c)
-    bs = min(block_s, n)
-    grid = (-(-c // bc), -(-n // bs))
-    return pl.pallas_call(
+    bc, bs, bd = min(block_c, c), min(block_s, n), min(_BLOCK_D, n)
+    du = _pad_to(du, bs, bc)
+    dn = _pad_to(dn, bc, bd)
+    traffic = _pad_to(traffic, bs, bd)
+    dist = _pad_to(dist, bs, bd)
+    grid = (dn.shape[0] // bc, dn.shape[1] // bd, du.shape[0] // bs)
+    v = pl.pallas_call(
         functools.partial(_v_kernel, offset=offset),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bs, bc), lambda cb, sb: (sb, cb)),   # du
-            pl.BlockSpec((bc, n), lambda cb, sb: (cb, 0)),     # dn
-            pl.BlockSpec((bs, n), lambda cb, sb: (sb, 0)),     # traffic
-            pl.BlockSpec((bs, n), lambda cb, sb: (sb, 0)),     # dist
+            pl.BlockSpec((bs, bc), lambda cb, db, sb: (sb, cb)),  # du
+            pl.BlockSpec((bc, bd), lambda cb, db, sb: (cb, db)),  # dn
+            pl.BlockSpec((bs, bd), lambda cb, db, sb: (sb, db)),  # traffic
+            pl.BlockSpec((bs, bd), lambda cb, db, sb: (sb, db)),  # dist
         ],
-        out_specs=pl.BlockSpec((bc, n), lambda cb, sb: (cb, 0)),
-        out_shape=jax.ShapeDtypeStruct((c, n), traffic.dtype),
+        out_specs=pl.BlockSpec((bc, bd), lambda cb, db, sb: (cb, db)),
+        out_shape=jax.ShapeDtypeStruct(dn.shape, traffic.dtype),
         interpret=interpret,
     )(du, dn, traffic, dist)
+    return v[:c, :n]
+
+
+@functools.partial(jax.jit, static_argnames=("block_c", "block_s",
+                                             "offset", "interpret"))
+def possibility_weights_pallas(du, dn, dsn, tn, traffic, dist,
+                               block_c: int = 128, block_s: int = 32,
+                               offset: int = 1,
+                               interpret: bool = False):
+    """(W, W_drn) per channel: W is the row sum of
+    :func:`possibility_v_pallas`, W_drn the O(N·C) draining term."""
+    v = possibility_v_pallas(du, dn, traffic, dist, block_c=block_c,
+                             block_s=block_s, offset=offset,
+                             interpret=interpret)
+    drn = ((du + offset) == dsn).astype(traffic.dtype)
+    return v.sum(1), jnp.sum(drn * tn, axis=0)

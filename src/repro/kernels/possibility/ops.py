@@ -1,11 +1,10 @@
 """Public op: possibility weights with host-side gather preparation.
 
-Defaults are the COMPILED paths: on backends with Pallas support
-(TPU/GPU) the Pallas kernel runs compiled; elsewhere (CPU) the call
-auto-falls back to the dense jnp oracle, which XLA jit-compiles — the
-interpreter is never the default anywhere.  Pass ``use_pallas`` /
-``interpret`` explicitly to pin a path (tests run the Pallas kernel in
-interpret mode on CPU to keep it covered).
+Defaults are the COMPILED paths: on TPU the Pallas kernel runs compiled;
+elsewhere the call auto-falls back to the dense jnp oracle, which XLA
+jit-compiles — the interpreter is never the default anywhere.  Pass
+``use_pallas`` / ``interpret`` explicitly to pin a path (tests run the
+Pallas kernel in interpret mode on CPU to keep it covered).
 """
 
 from __future__ import annotations
@@ -24,8 +23,9 @@ _dense_jit = functools.partial(jax.jit, static_argnames=("offset",))(
 
 
 def backend_supports_pallas() -> bool:
-    """Compiled Pallas lowering exists on TPU/GPU only."""
-    return jax.default_backend() in ("tpu", "gpu")
+    """Compiled Pallas lowering of the possibility kernel: TPU only, the
+    one backend it is compiled for (``tests/test_chip_compile.py``)."""
+    return jax.default_backend() == "tpu"
 
 
 def _prepare(dist, traffic, channels):

@@ -1,6 +1,7 @@
 """Fused flit-step kernel: the simulator's per-cycle hot path as one
-on-chip pass (Pallas on TPU/GPU, fused dense jnp on CPU), bit-identical
-to the unfused ``repro.noc.sim`` step it replaces."""
+pass (the fused body compiled by XLA; the Pallas kernels of the same
+body run in interpret mode only), bit-identical to the unfused
+``repro.noc.sim`` step it replaces."""
 
 from .ops import (backend_supports_pallas, make_step, resolve_path,
                   state_footprint_bytes, vmem_budget_bytes)

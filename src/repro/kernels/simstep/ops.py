@@ -1,14 +1,16 @@
 """Public op: the fused flit-step with backend-aware dispatch.
 
-Mirrors :mod:`repro.kernels.possibility.ops`: defaults are the COMPILED
-paths.  On backends with Pallas support (TPU/GPU) the fused cycle runs
-as a Pallas kernel — whole-array when the state fits the VMEM budget,
-else the blocked node-tile grid (:mod:`.kernel`); elsewhere (CPU) the
-call auto-falls back to the fused dense jnp body, which XLA
-jit-compiles — the interpreter is never the default anywhere.  Pass
-``use_pallas`` / ``interpret`` explicitly (or set
-``SimConfig.sim_tile_nodes``) to pin a path; the differential battery
-runs the Pallas kernels in interpret mode on CPU to keep them covered.
+The fused cycle body runs compiled by XLA on every backend today: no
+backend lowers it as a Pallas kernel (:func:`backend_supports_pallas`
+says why), so the auto ladder resolves to the fused dense body and the
+interpreter is never the default anywhere.  The ladder itself — the
+whole-array kernel while the state fits the VMEM budget, else the
+blocked node-tile grid (:mod:`.kernel`), else dense — stays in place for
+a backend that can lower the kernels, and is tested with
+``supported=True``.  Pass ``use_pallas`` / ``interpret`` explicitly (or
+set ``SimConfig.sim_tile_nodes``) to pin a path; the differential
+battery runs the Pallas kernels in interpret mode on CPU to keep them
+covered.
 
 Capacity math is DERIVED, not hand-maintained: the footprint the gate
 compares against the budget comes from ``jax.eval_shape`` over the
@@ -17,8 +19,9 @@ actual initial state plus the abstract table shapes
 rings, watchdog counters, whatever comes next) is counted the moment it
 exists.  The budget itself is overridable (``SIMSTEP_VMEM_BUDGET`` env,
 ``--simstep-vmem-budget`` on the benchmark CLI), and every dispatch
-decision is logged once per distinct (path, size, algo, tile) via
-:class:`repro.obs.log.EventLog` — set ``SIMSTEP_LOG=0`` to silence.
+decision is logged once per distinct (path, size, algo, tile), with the
+reason for it, via :class:`repro.obs.log.EventLog` — set
+``SIMSTEP_LOG=0`` to silence.
 
 The entry point is :func:`make_step`: it returns a drop-in replacement
 for the unfused ``repro.noc.sim._make_step`` transition — same
@@ -42,8 +45,16 @@ from .ref import (MOV_W, TABLE_TILE_AXES, make_cycle_fn, make_cycle_parts,
 
 
 def backend_supports_pallas() -> bool:
-    """Compiled Pallas lowering exists on TPU/GPU only."""
-    return jax.default_backend() in ("tpu", "gpu")
+    """Whether the fused cycle body lowers as a compiled Pallas kernel on
+    this backend.  On none yet.  The body (``ref.py``) is generic jnp full
+    of gathers and scatters.  Compiled for a TPU v5e, Mosaic stops the
+    whole-array kernel with an ``AssertionError`` in
+    ``_gather_lowering_rule``, and refuses the blocked kernel's node-tiled
+    rank-1 blocks ("rank 1 block shapes ... multiple of the tiling size
+    (128)"); the blocked tile body would then hit the same gather
+    refusal.  Until the body is rewritten to lower, the ladder resolves
+    to the fused body compiled by XLA, on TPU as on CPU."""
+    return False
 
 
 # Default on-chip budget (VMEM is ~16 MB/core on TPU, minus headroom for
@@ -139,14 +150,15 @@ def resolve_path(meta: dict, cfg: SimConfig,
     * ``use_pallas=False`` pins the fused dense body.
     * ``cfg.sim_tile_nodes > 0`` pins the blocked kernel at that tile.
     * ``use_pallas=True`` pins the whole-array kernel.
-    * auto (all ``None``/0): on a Pallas backend, whole-array while the
+    * auto (all ``None``/0): where Pallas lowers (``supported``, by
+      default :func:`backend_supports_pallas`), whole-array while the
       state fits the budget, else the largest fitting tile, else dense;
-      on CPU, dense.
+      elsewhere, dense.
 
     ``interpret`` resolves to compiled where supported; forcing a
-    Pallas path on CPU runs the interpreter for the whole-array kernel,
-    while the blocked path prefers its compiled ``vmap`` flavor unless
-    ``interpret=True`` asks for the Pallas interpreter explicitly.
+    Pallas path elsewhere runs the interpreter for the whole-array
+    kernel, while the blocked path prefers its compiled ``vmap`` flavor
+    unless ``interpret=True`` asks for the Pallas interpreter explicitly.
     """
     supported = (backend_supports_pallas() if supported is None
                  else supported)
@@ -178,8 +190,25 @@ _LOG = EventLog(
 _LOGGED: set = set()
 
 
+def _dispatch_reason(path: str, cfg: SimConfig,
+                     use_pallas: bool | None) -> str:
+    """Why :func:`resolve_path` took ``path``, for the dispatch event."""
+    if use_pallas is False:
+        return "pinned: use_pallas=False"
+    if int(getattr(cfg, "sim_tile_nodes", 0)) > 0:
+        return "pinned: sim_tile_nodes"
+    if use_pallas:
+        return "pinned: use_pallas=True"
+    if not backend_supports_pallas():
+        return (f"no compiled Pallas lowering of the cycle body on "
+                f"{jax.default_backend()}")
+    return {"whole": "state fits the VMEM budget",
+            "blocked": "largest node tile in budget",
+            "dense": "no node tile fits the VMEM budget"}[path]
+
+
 def _log_dispatch(path: str, meta: dict, cfg: SimConfig, tile: int,
-                  interpret: bool) -> None:
+                  interpret: bool, use_pallas: bool | None) -> None:
     key = (path, meta["N"], int(cfg.algo), tile, bool(interpret))
     if key in _LOGGED:
         return
@@ -187,6 +216,7 @@ def _log_dispatch(path: str, meta: dict, cfg: SimConfig, tile: int,
     _LOG.event("simstep_dispatch", cat="kernel", path=path,
                nodes=meta["N"], algo=Algo(cfg.algo).name,
                tile_nodes=tile, interpret=bool(interpret),
+               reason=_dispatch_reason(path, cfg, use_pallas),
                footprint_bytes=state_footprint_bytes(meta, cfg),
                budget_bytes=vmem_budget_bytes())
 
@@ -215,7 +245,7 @@ def make_step(meta: dict, cfg: SimConfig,
             interpret=interp and not compiled)
     else:
         run_cycle = make_cycle_fn(meta, cfg)
-    _log_dispatch(path, meta, cfg, tile, interp)
+    _log_dispatch(path, meta, cfg, tile, interp, use_pallas)
     algo = Algo(cfg.algo)
     n, ndim = meta["N"], meta["NDIM"]
 

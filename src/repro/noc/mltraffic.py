@@ -397,6 +397,9 @@ def _derive_subprocess(spec: WorkloadSpec, timeout_s: float) -> MLWorkload:
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
+    # the child lowers on virtual host devices; on a chip machine the
+    # parent already holds the accelerator, so the child must not try
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={spec.num_devices}")
     env["PYTHONPATH"] = os.pathsep.join(

@@ -12,7 +12,7 @@ The whole per-cycle pipeline is pure jnp and runs under ``lax.scan``; one
 jit-compilation per (topology, algorithm, packet-length) triple.  By
 default (``SimConfig.use_kernel``) the per-cycle transition is the fused
 flit-step kernel of :mod:`repro.kernels.simstep` — one on-chip pass over
-the packed flit records (Pallas on TPU/GPU, fused dense jnp on CPU),
+the packed flit records (the fused dense jnp body, compiled by XLA),
 bit-identical to the unfused chain in :func:`_make_step`, which stays as
 the differential-testing oracle.  Campaign lane batches can additionally
 run under an explicit ``shard_map`` over all local devices with donated
@@ -297,8 +297,8 @@ def _make_step(meta: dict, cfg: SimConfig):
     traffic patterns and injection rates share one compilation per algo).
 
     With ``cfg.use_kernel`` (the default) the transition is the fused
-    flit-step kernel (:mod:`repro.kernels.simstep`: one Pallas pass on
-    TPU/GPU, the fused dense jnp body on CPU) — bit-identical to the
+    flit-step kernel (:mod:`repro.kernels.simstep`: the fused dense jnp
+    body, compiled by XLA on every backend) — bit-identical to the
     unfused chain below, which remains the differential-testing oracle
     and the ``simstep_scale`` benchmark baseline."""
     if cfg.use_kernel:
@@ -712,6 +712,28 @@ def _make_step(meta: dict, cfg: SimConfig):
     return step
 
 
+class _PinnedPrng:
+    """A jitted runner, traced and run under the non-partitionable
+    threefry bit layout.
+
+    Every simulated statistic is a pure function of its point's threefry
+    stream, and the goldens pin the streams of that layout.  JAX 0.5 made
+    the partitionable layout the default, which draws other bits from the
+    same key.  The layout is part of the jit key, so the pin holds for the
+    runner's own calls only and leaves the process-wide default alone."""
+
+    def __init__(self, fn):
+        self._fn = fn
+
+    def __call__(self, *args):
+        with jax.threefry_partitionable(False):
+            return self._fn(*args)
+
+    def lower(self, *args):
+        with jax.threefry_partitionable(False):
+            return self._fn.lower(*args)
+
+
 @functools.lru_cache(maxsize=None)
 def _get_runner(meta_key: tuple, cfg_key: tuple, num_cycles: int):
     """One jit compilation per (mesh size, algo, flow-control params,
@@ -728,7 +750,7 @@ def _get_runner(meta_key: tuple, cfg_key: tuple, num_cycles: int):
         state["cycle0"] = state["cycle0"] + num_cycles
         return state
 
-    return jax.jit(jax.vmap(run, in_axes=(None, 0)))
+    return _PinnedPrng(jax.jit(jax.vmap(run, in_axes=(None, 0))))
 
 
 @functools.lru_cache(maxsize=None)
@@ -743,7 +765,6 @@ def _get_sharded_runner(meta_key: tuple, cfg_key: tuple, num_cycles: int,
     plane's epoch loop update multi-MB flit buffers in place instead of
     reallocating them per call.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     meta = dict(meta_key)
@@ -757,10 +778,10 @@ def _get_sharded_runner(meta_key: tuple, cfg_key: tuple, num_cycles: int,
         return state
 
     mesh = Mesh(np.array(jax.devices()[:ndev]), ("lane",))
-    fn = shard_map(jax.vmap(run, in_axes=(None, 0)), mesh=mesh,
-                   in_specs=(PartitionSpec(), PartitionSpec("lane")),
-                   out_specs=PartitionSpec("lane"), check_rep=False)
-    return jax.jit(fn, donate_argnums=(1,))
+    fn = jax.shard_map(jax.vmap(run, in_axes=(None, 0)), mesh=mesh,
+                       in_specs=(PartitionSpec(), PartitionSpec("lane")),
+                       out_specs=PartitionSpec("lane"), check_vma=False)
+    return _PinnedPrng(jax.jit(fn, donate_argnums=(1,)))
 
 
 def _cfg_key(cfg: SimConfig) -> tuple:

@@ -59,15 +59,16 @@ class SimConfig:
     lat_bins: int = 96            # latency histogram bins (percentiles)
     lat_bin_width: int = 8        # cycles per histogram bin; last = overflow
     # Per-cycle hot path: True runs the fused flit-step kernel
-    # (repro.kernels.simstep — Pallas on TPU/GPU, the fused dense jnp
-    # fallback on CPU); False runs the legacy unfused jnp step, kept as
+    # (repro.kernels.simstep — the fused dense jnp body, compiled by
+    # XLA on every backend); False runs the legacy unfused jnp step, kept as
     # the differential-testing oracle (tests/test_simstep_kernel.py) and
     # the simstep_scale benchmark baseline.  Both are bit-identical.
     use_kernel: bool = True
     # Blocked simstep kernel (repro.kernels.simstep): tile the per-cycle
     # body over node ranges of this size so only one tile's flit/queue
     # records are resident on chip at a time (double-buffered HBM→VMEM
-    # streaming on TPU/GPU; a vmapped-tiles XLA flavor on CPU).  Must
+    # streaming where Pallas lowers it; a vmapped-tiles XLA flavor
+    # elsewhere, which is every backend today).  Must
     # divide the node count.  0 = auto: the dispatcher
     # (repro.kernels.simstep.ops.make_step) picks whole-array when the
     # state fits the VMEM budget, else the largest fitting tile, else

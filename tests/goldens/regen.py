@@ -160,6 +160,8 @@ def main(argv=None):
                          "fixtures, which CI attests by regenerating "
                          "with each and cross-diffing")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_checkout_cache
+    use_checkout_cache(os.path.join(os.path.dirname(__file__), "..", ".."))
     to_path = SIM_PATHS[args.sim_path]
     golden_path, ctrl_path = GOLDEN_PATH, CTRL_GOLDEN_PATH
     if args.out:

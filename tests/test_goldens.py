@@ -94,6 +94,14 @@ def test_golden_conservation(computed):
         assert pt["reorder"] == 0, key  # XY and BiDOR are in-order
 
 
+def test_golden_prng_layout_leaves_process_default(computed):
+    """The simulator pins the threefry layout the goldens were cut with
+    for its own runner calls only; the process-wide flag is untouched."""
+    import jax
+    assert computed["points"]
+    assert jax.config.jax_threefry_partitionable is True
+
+
 def test_ctrl_golden_point_set_matches(ctrl_golden, ctrl_computed):
     assert set(ctrl_computed["points"]) == set(ctrl_golden["points"])
 

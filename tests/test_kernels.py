@@ -80,6 +80,32 @@ def test_possibility_v_pallas_matches_dense():
         rtol=1e-5, atol=1e-7)
 
 
+def test_possibility_v_pallas_multi_block_padded():
+    """V entry by entry at 12×12 (N=144, C=528): two destination blocks
+    and zero padding on the channel, source and destination axes."""
+    from repro.kernels.possibility.kernel import possibility_v_pallas
+    from repro.kernels.possibility.ops import _prepare
+    interpret = not poss_ops.backend_supports_pallas()
+    topo = mesh2d(12, 12)
+    rng = np.random.default_rng(7)
+    t = rng.random((topo.num_nodes,) * 2)
+    np.fill_diagonal(t, 0.0)
+    t /= t.sum()
+    du, dn, dsn, tn, tm, dist = _prepare(topo.distances, t, topo.channels)
+    v = np.asarray(possibility_v_pallas(du, dn, tm, dist,
+                                        interpret=interpret))
+    du, dn, tm, dist = (np.asarray(a) for a in (du, dn, tm, dist))
+    v_ref = np.concatenate([
+        np.einsum("csd,sd->cd",
+                  (du.T[c0:c0 + 128, :, None] + 1 + dn[c0:c0 + 128, None]
+                   == dist[None]).astype(np.float64), tm)
+        for c0 in range(0, topo.num_channels, 128)])
+    assert v.shape == v_ref.shape == (528, 144)
+    np.testing.assert_allclose(v, v_ref, rtol=1e-5, atol=1e-9)
+    w_ref, _ = possibility_oracle(topo.distances, t, topo.channels)
+    np.testing.assert_allclose(v.sum(1), w_ref, rtol=1e-5, atol=1e-7)
+
+
 @settings(max_examples=10, deadline=None)
 @given(st.integers(3, 6), st.integers(3, 5), st.integers(0, 2**31 - 1))
 def test_possibility_kernel_random_traffic(w, h, seed):

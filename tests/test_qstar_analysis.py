@@ -23,6 +23,8 @@ def _random_traffic(topo, rnd):
     n = topo.num_nodes
     t = np.array([[rnd.random() for _ in range(n)] for _ in range(n)])
     np.fill_diagonal(t, 0.0)
+    if not t.sum():  # an all-zero draw is no traffic matrix: uniform
+        t = 1.0 - np.eye(n)
     return t / t.sum()
 
 
