@@ -255,9 +255,10 @@ def phase_scale(sizes: Sizes) -> None:
 
 
 def phase_ctrl(sizes: Sizes) -> None:
-    from repro.core import plan_fast, torus, traffic
+    from repro.core import torus, traffic
     from repro.noc import (Algo, LinkFail, ReplanConfig, Scenario,
                            SimConfig, TrafficDrift, run_controlled)
+    from repro.obs.trace import TraceWriter, read_trace
 
     k, c = sizes.ctrl_side, sizes.ctrl_cycles
     topo = torus(k, k)
@@ -267,19 +268,23 @@ def phase_ctrl(sizes: Sizes) -> None:
         events=(LinkFail(cycle=c // 3, links=((0, 1), (1, 0))),
                 TrafficDrift(cycle=2 * c // 3,
                              traffic=traffic.transpose(topo))))
-    builds0 = plan_fast.DEVICE_BUILDS
-    with _CompileClock() as clock:
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp, \
+            _CompileClock() as clock:
+        writer = TraceWriter(os.path.join(tmp, "ctrl_trace.jsonl"))
         t0 = time.perf_counter()
         res = run_controlled(topo, traffic.uniform(topo),
                              SimConfig(algo=Algo.BIDOR, cycles=c,
                                        warmup=c // 6),
-                             scen, rates=[0.1], seeds=[0, 1])
+                             scen, rates=[0.1], seeds=[0, 1],
+                             tracer=writer)
         wall = time.perf_counter() - t0
+        writer.close()
+        events = read_trace(writer.path)
     _check_conservation(res.results, "ctrl")
     _check(any(r.trigger == "fault" for r in res.replans),
            f"ctrl: no replan after the link failure ({res.replans})")
     # the seed plan, then at least one replan, on the device planner
-    builds = plan_fast.DEVICE_BUILDS - builds0
+    builds = sum(e["name"] == "plan_device" for e in events)
     _check(builds >= 2, f"ctrl: {builds} device plan builds")
     _say("ctrl", nodes=topo.num_nodes, lanes=len(res.points), cycles=c,
          replans=",".join(f"{r.trigger}@{r.cycle}" for r in res.replans),

@@ -182,7 +182,8 @@ def bidor(topo: Topology, w_nr: np.ndarray,
 
 
 def greedy_refine(topo: Topology, traffic, table: BiDORTable,
-                  sweeps: int = 4) -> BiDORTable:
+                  sweeps: int = 4, *, stats: dict | None = None
+                  ) -> BiDORTable:
     """BiDOR-G (beyond paper): greedy max-link-load refinement.
 
     BiDOR minimizes each pair's *own* path cost against the static w_NR
@@ -191,6 +192,10 @@ def greedy_refine(topo: Topology, traffic, table: BiDORTable,
     flip a pair's dimension order whenever that lowers the current maximum
     link load (recomputed incrementally).  Still fully offline/quasi-static
     — the output is the same bitmap artifact.
+
+    ``stats``, when given, receives the work counts: ``pairs`` (pairs
+    with traffic that each sweep visits), ``sweeps_run`` and ``changed``
+    (entries of the output that differ from the input table).
     """
     import numpy as _np
     from .routes import walk_routes
@@ -231,7 +236,9 @@ def greedy_refine(topo: Topology, traffic, table: BiDORTable,
              if s != d and t[s, d] > 0
              and not (unroutable is not None and unroutable[s, d])]
     pairs.sort(key=lambda p: -t[p])
+    sweeps_run = 0
     for _ in range(sweeps):
+        sweeps_run += 1
         changed = 0
         for s, d in pairs:
             cur = int(choice[s, d])
@@ -262,6 +269,9 @@ def greedy_refine(topo: Topology, traffic, table: BiDORTable,
                 changed += 1
         if changed == 0:
             break
+    if stats is not None:
+        stats.update(pairs=len(pairs), sweeps_run=sweeps_run,
+                     changed=int((choice != table.choice).sum()))
     return BiDORTable(choice=choice, orders=orders, costs=table.costs,
                       port_tables=table.port_tables,
                       unroutable=table.unroutable)

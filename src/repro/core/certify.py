@@ -52,6 +52,8 @@ import time
 
 import numpy as np
 
+from repro.obs.trace import NULL_TRACER, span
+
 from .bidor import BiDORTable
 from .topology import Topology
 
@@ -407,14 +409,23 @@ def certify_ports(topo: Topology, port_tables: np.ndarray,
         repair prohibits the lowest-N-Rank-weight turns first.
       repair: attempt turn-prohibition repair on a cyclic CDG; False
         certifies only (verdict ``clean`` or ``rejected``).
-      tracer: optional :class:`repro.obs.trace.TraceWriter`; emits a
-        ``certify`` span plus a per-check verdict instant.
+      tracer: optional tracer (:mod:`repro.obs.trace`); records a
+        ``certify`` span whose args carry ``label`` and the verdict.
 
     Returns a :class:`Certificate`.  Raising on rejection is the
     caller's policy (the plan gates raise :class:`CertificationError`).
     """
+    with span(NULL_TRACER if tracer is None else tracer, "certify",
+              cat="certify", label=label) as a:
+        cert = _certify_ports(topo, port_tables, choice, unroutable,
+                              traffic, w_nr, repair, max_repair_rounds)
+        a.update(cert.trace_args())
+    return cert
+
+
+def _certify_ports(topo, port_tables, choice, unroutable, traffic, w_nr,
+                   repair, max_repair_rounds) -> Certificate:
     t0 = time.perf_counter()
-    tr0 = tracer.now_us() if tracer is not None and tracer.enabled else 0.0
     n = topo.num_nodes
     port_tables = np.asarray(port_tables)
     num_orders = int(port_tables.shape[0])
@@ -435,25 +446,16 @@ def certify_ports(topo: Topology, port_tables: np.ndarray,
     cyclic0 = int(cyc.sum())
 
     if cyclic0 == 0 or not repair:
-        cert = Certificate(
+        return Certificate(
             verdict="clean" if cyclic0 == 0 else "rejected",
             cdg_nodes=num_cdg_nodes, cdg_edges=int(edges.shape[0]),
             cyclic_nodes=cyclic0,
             prohibited_turns=np.zeros((0, 2), np.int32),
             invalid_pairs=int(invalid.sum()),
             wall_ms=(time.perf_counter() - t0) * 1e3)
-    else:
-        cert = _repair(topo, port_tables, choice, active, traffic, w_nr,
-                       hops, max_repair_rounds, num_cdg_nodes,
-                       int(edges.shape[0]), cyclic0, int(invalid.sum()),
-                       t0)
-    if tracer is not None and tracer.enabled:
-        tracer.complete("certify", tr0, tracer.now_us() - tr0,
-                        cat="certify",
-                        args=dict(cert.trace_args(), label=label))
-        tracer.instant(f"certify_{cert.verdict}", cat="certify",
-                       args=dict(cert.trace_args(), label=label))
-    return cert
+    return _repair(topo, port_tables, choice, active, traffic, w_nr, hops,
+                   max_repair_rounds, num_cdg_nodes, int(edges.shape[0]),
+                   cyclic0, int(invalid.sum()), t0)
 
 
 def _ejects_at_destination(topo: Topology,

@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, span
 
 from .bidor import TIE_TOL, BiDORTable
 from .certify import CertificationError, apply_repair, certify_table
@@ -72,10 +72,6 @@ from .topology import Topology
 
 __all__ = ["build_plan_fast", "build_plans_batched", "plan_statics",
            "joint_possibility_fast", "plan_cache_key"]
-
-# Jitted plan computations actually executed (cache bypasses bump nothing):
-# the "did a warm re-run re-plan?" signal for tests and service logs.
-DEVICE_BUILDS = 0
 
 
 def _resolve_precision(precision: str) -> str:
@@ -496,11 +492,11 @@ def build_plan_fast(topo: Topology, traffic: np.ndarray, *,
     cold (``w0``-less) builds are served from / stored into it by content
     key, skipping the device computation entirely on a hit.
 
-    ``tracer`` (a :class:`repro.obs.trace.TraceWriter`) records the
-    build as a span — statics/compile+device wall split in its args —
-    and cache hits as instants.
+    ``tracer`` (:mod:`repro.obs.trace`) records the build as a
+    ``build_plan_fast`` span holding ``plan_statics``, ``plan_device``,
+    ``plan_assemble`` and the gate's ``certify``, and cache hits as
+    instants.
     """
-    global DEVICE_BUILDS
     tracer = tracer if tracer is not None else NULL_TRACER
     key, hit = _cache_lookup(cache, topo, traffic, down_channels,
                              k_orders, w_th, iter_th, precision, w0)
@@ -513,35 +509,34 @@ def build_plan_fast(topo: Topology, traffic: np.ndarray, *,
             return dataclasses.replace(hit, cert=cert)
         # pre-certifier entry (or a stored repair): re-run the gate
         return gate_plan(topo, hit, tracer=tracer, label="cache_hit")
-    t_all = tracer.now_us()
-    statics = plan_statics(topo, binary_only=not k_orders,
-                           use_pallas=use_pallas)
-    down, dist, live, down_pair = _fault_arrays(topo, statics,
-                                                down_channels)
-    t_dev = tracer.now_us()
-    DEVICE_BUILDS += 1
-    if cache is not None:
-        cache.stats.device_builds += 1
-    with _precision_scope(precision):
-        t = jnp.asarray(np.asarray(traffic, np.float64))
-        w0_eff = jnp.asarray(np.asarray(
-            initial_weights(traffic) if w0 is None else w0, np.float64))
-        out = statics.core_batched(jnp.asarray(dist), t[None],
-                                   w0_eff[None],
-                                   jnp.asarray([w0 is not None]),
-                                   jnp.asarray(live), jnp.asarray(down_pair),
-                                   jnp.asarray(float(w_th)),
-                                   jnp.int32(iter_th))
-        out = {k: v[0] for k, v in jax.device_get(out).items()}
-    plan = _assemble_plan(topo, traffic, statics, out, bool(down.size))
-    plan = gate_plan(topo, plan, tracer=tracer, label="build_plan_fast")
-    t_end = tracer.now_us()
-    tracer.complete(
-        "build_plan_fast", t_all, t_end - t_all, cat="plan",
-        args={"nodes": topo.num_nodes, "warm": w0 is not None,
-              "faults": int(down.size),
-              "statics_ms": round((t_dev - t_all) / 1e3, 3),
-              "device_ms": round((t_end - t_dev) / 1e3, 3)})
+    warm = w0 is not None
+    with span(tracer, "build_plan_fast", cat="plan", nodes=topo.num_nodes,
+              warm=warm) as a:
+        with span(tracer, "plan_statics", cat="plan") as sa:
+            statics = plan_statics(topo, binary_only=not k_orders,
+                                   use_pallas=use_pallas)
+            down, dist, live, down_pair = _fault_arrays(topo, statics,
+                                                        down_channels)
+            sa["faults"] = a["faults"] = int(down.size)
+        if cache is not None:
+            cache.stats.device_builds += 1
+        with span(tracer, "plan_device", cat="plan", warm=warm), \
+                _precision_scope(precision):
+            t = jnp.asarray(np.asarray(traffic, np.float64))
+            w0_eff = jnp.asarray(np.asarray(
+                initial_weights(traffic) if w0 is None else w0,
+                np.float64))
+            out = statics.core_batched(jnp.asarray(dist), t[None],
+                                       w0_eff[None], jnp.asarray([warm]),
+                                       jnp.asarray(live),
+                                       jnp.asarray(down_pair),
+                                       jnp.asarray(float(w_th)),
+                                       jnp.int32(iter_th))
+            out = {k: v[0] for k, v in jax.device_get(out).items()}
+        with span(tracer, "plan_assemble", cat="plan"):
+            plan = _assemble_plan(topo, traffic, statics, out,
+                                  bool(down.size))
+        plan = gate_plan(topo, plan, tracer=tracer, label="build_plan_fast")
     if key is not None:
         cache.put(key, plan, k_orders=k_orders, cert=plan.cert)
     return plan
@@ -569,7 +564,6 @@ def build_plans_batched(topo: Topology, traffics, *,
     runs at all.  ``tracer`` records the batched build as a span and
     per-lane cache hits/misses as instants.
     """
-    global DEVICE_BUILDS
     tracer = tracer if tracer is not None else NULL_TRACER
     statics = plan_statics(topo, binary_only=not k_orders,
                            use_pallas=use_pallas)
@@ -620,9 +614,9 @@ def build_plans_batched(topo: Topology, traffics, *,
     # in slices that keep the peak working set bounded
     group = max(1, (1 << 26) // max(_v_block(n) * n * n, 1))
     plans = []
-    DEVICE_BUILDS += 1
-    t_span = tracer.now_us()
-    with _precision_scope(precision):
+    with span(tracer, "build_plans_batched", cat="plan",
+              nodes=topo.num_nodes, lanes=len(tms), faults=int(down.size)), \
+            _precision_scope(precision):
         for lo in range(0, len(tms), group):
             tms_g, w0s_g = tms[lo:lo + group], w0s[lo:lo + group]
             t_b = jnp.asarray(np.stack(tms_g))
@@ -641,10 +635,6 @@ def build_plans_batched(topo: Topology, traffics, *,
                                       have_down=bool(down.size))
                 plans.append(gate_plan(topo, plan, tracer=tracer,
                                        label="build_plans_batched"))
-    tracer.complete("build_plans_batched", t_span,
-                    tracer.now_us() - t_span, cat="plan",
-                    args={"nodes": topo.num_nodes, "lanes": len(tms),
-                          "faults": int(down.size)})
     return plans
 
 

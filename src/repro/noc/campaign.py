@@ -42,9 +42,10 @@ from repro.core.plan_fast import build_plans_batched
 from repro.core.topology import Topology
 from repro.obs.log import EventLog
 from repro.obs.probe import Telemetry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, span, tagged
 from .sim import (build_tables, get_runner, make_states, postprocess,
-                  queue_occupancy, source_queue_meta, static_bw_slots)
+                  queue_occupancy, runner_builds, source_queue_meta,
+                  static_bw_slots)
 from .simconfig import Algo, SimConfig, SimResult
 
 __all__ = ["CampaignSpec", "CampaignPoint", "CampaignResult",
@@ -352,12 +353,13 @@ def csv_rows(points: Sequence[CampaignPoint]) -> list[list]:
 
 
 def _run_cell(spec: CampaignSpec, cfg: SimConfig, tables, meta,
-              points: list[tuple[float, int]]):
+              points: list[tuple[float, int]], tracer=NULL_TRACER):
     """Advance one (algo, pattern) cell; returns (host state, sat flags).
 
     The cell is one vmapped batch over ``points``.  With ``spec.chunk``
     set, execution proceeds in chunk-cycle slices so the host can stop the
-    whole batch as soon as every lane is saturated.
+    whole batch as soon as every lane is saturated.  Each runner call is
+    a ``chunk`` span of ``tracer``.
     """
     batched = make_states(meta, cfg, points)
     total = int(cfg.cycles)
@@ -367,10 +369,13 @@ def _run_cell(spec: CampaignSpec, cfg: SimConfig, tables, meta,
     done = 0
     while done < total:
         step_cycles = min(chunk, total - done)
-        runner = get_runner(meta, cfg, step_cycles,
-                            num_lanes=len(points),
-                            multi_device=spec.multi_device)
-        batched = runner(tables, batched)
+        with span(tracer, "chunk", cat="sim", cycles=step_cycles) as a:
+            builds = runner_builds()
+            runner = get_runner(meta, cfg, step_cycles,
+                                num_lanes=len(points),
+                                multi_device=spec.multi_device)
+            a["compiled"] = runner_builds() > builds
+            batched = runner(tables, batched)
         done += step_cycles
         if done > cfg.warmup:
             # saturation accumulates from post-warmup reads only — a
@@ -513,6 +518,7 @@ class CampaignExecutor:
         self.points = [(float(r), int(s))
                        for r in spec.rates for s in spec.seeds]
         self._prepped: dict[int, list[_ItemPrep]] = {}
+        self._plan_builds = 0   # build_plans_batched calls (trace args)
 
     # ------------------------------------------------------------- #
     def _build_plans(self, topo: Topology, items, need: list[int]):
@@ -525,6 +531,7 @@ class CampaignExecutor:
         dc = down if down.size else None
         cache = self.plan_cache
         if cache is None:
+            self._plan_builds += 1
             built = build_plans_batched(topo, [items[i][1] for i in need],
                                         down_channels=dc,
                                         tracer=self.tracer)
@@ -551,6 +558,7 @@ class CampaignExecutor:
             else:
                 miss.append((i, key))
         if miss:
+            self._plan_builds += 1
             built = build_plans_batched(
                 topo, [items[i][1] for i, _ in miss], down_channels=dc,
                 tracer=self.tracer)
@@ -617,53 +625,53 @@ class CampaignExecutor:
         chunked call and checkpoint only at completion.
         """
         spec = self.spec
+        tracer = tagged(self.tracer, slug=key.slug)
         topo = spec.topo_axis[key.topo_i]
-        prep = self._prep_topo(key.topo_i)[key.item_i]
+        with span(tracer, "prep_topo", cat="campaign") as a:
+            builds = self._plan_builds
+            prep = self._prep_topo(key.topo_i)[key.item_i]
+            a["cached"] = self._plan_builds == builds
         algo = key.algo
         cfg = spec.base.replace(algo=algo)
         scen = spec.scenarios[key.scen_i] if key.scen_i >= 0 else None
-        t0 = time.perf_counter()
-        tc0 = self.tracer.now_us() if self.tracer.enabled else 0.0
-        cell_tm = prep.bidor_tm if algo == Algo.BIDOR else prep.tm
-        telemetry = None
-        if scen is None:
-            tables, meta = build_tables(
-                topo, cell_tm,
-                prep.table if algo == Algo.BIDOR else None, cfg.num_vcs)
-            host, sat = _run_cell(spec, cfg, tables, meta, self.points)
-            results = []
-            for i, (rate, seed) in enumerate(self.points):
-                o = jax.tree.map(lambda x: x[i], host)
-                results.append(postprocess(
-                    o, cfg, topo, rate=rate, seed=seed,
-                    saturated=bool(sat[i])))
-            telemetry = Telemetry.from_state(host, cfg)
-            if telemetry is not None:
-                telemetry = telemetry.with_bw(static_bw_slots(topo, cfg))
-        else:
-            from .ctrl import run_controlled
-            ctrl_res = run_controlled(
-                topo, cell_tm, cfg, scen,
-                rates=[float(r) for r in spec.rates],
-                seeds=list(spec.seeds),
-                bidor_table=prep.table if algo == Algo.BIDOR else None,
-                nrank0=prep.nrank if algo == Algo.BIDOR else None,
-                sat_occupancy=spec.sat_occupancy,
-                multi_device=spec.multi_device,
-                checkpoint=checkpoint,
-                verbose=self.verbose,
-                tracer=self.tracer)
-            results = [ctrl_res.result_with_peak(i)
-                       for i in range(len(self.points))]
-            telemetry = ctrl_res.telemetry
-        dt = time.perf_counter() - t0
-        if self.tracer.enabled:
-            self.tracer.complete(
-                "cell", tc0, self.tracer.now_us() - tc0, cat="campaign",
-                args={"slug": key.slug, "topo": key.topo,
-                      "pattern": key.pattern, "algo": algo.name,
-                      "scenario": key.scenario,
-                      "lanes": len(self.points)})
+        with span(tracer, "cell", cat="campaign", topo=key.topo,
+                  pattern=key.pattern, algo=algo.name,
+                  scenario=key.scenario, lanes=len(self.points)):
+            t0 = time.perf_counter()
+            cell_tm = prep.bidor_tm if algo == Algo.BIDOR else prep.tm
+            telemetry = None
+            if scen is None:
+                tables, meta = build_tables(
+                    topo, cell_tm,
+                    prep.table if algo == Algo.BIDOR else None, cfg.num_vcs)
+                host, sat = _run_cell(spec, cfg, tables, meta, self.points,
+                                      tracer)
+                results = []
+                for i, (rate, seed) in enumerate(self.points):
+                    o = jax.tree.map(lambda x: x[i], host)
+                    results.append(postprocess(
+                        o, cfg, topo, rate=rate, seed=seed,
+                        saturated=bool(sat[i])))
+                telemetry = Telemetry.from_state(host, cfg)
+                if telemetry is not None:
+                    telemetry = telemetry.with_bw(static_bw_slots(topo, cfg))
+            else:
+                from .ctrl import run_controlled
+                ctrl_res = run_controlled(
+                    topo, cell_tm, cfg, scen,
+                    rates=[float(r) for r in spec.rates],
+                    seeds=list(spec.seeds),
+                    bidor_table=prep.table if algo == Algo.BIDOR else None,
+                    nrank0=prep.nrank if algo == Algo.BIDOR else None,
+                    sat_occupancy=spec.sat_occupancy,
+                    multi_device=spec.multi_device,
+                    checkpoint=checkpoint,
+                    verbose=self.verbose,
+                    tracer=tracer)
+                results = [ctrl_res.result_with_peak(i)
+                           for i in range(len(self.points))]
+                telemetry = ctrl_res.telemetry
+            dt = time.perf_counter() - t0
         self.log.event("cell_done",
                        f"campaign cell {key.topo:16s} {key.pattern:12s} "
                        f"{algo.name:8s} {key.scenario:12s} "

@@ -50,11 +50,11 @@ from repro.core.plan_fast import build_plan_fast
 from repro.core.topology import Topology
 from repro.obs.log import EventLog
 from repro.obs.probe import Telemetry, resolved_epoch
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, span, tagged
 from .watchdog import WatchdogReport
 from .sim import (build_tables, get_runner, make_states,
                   maybe_shard_states, postprocess, queue_occupancy,
-                  retarget_tables, source_queue_meta)
+                  retarget_tables, runner_builds, source_queue_meta)
 from .simconfig import Algo, SimConfig, SimResult
 
 __all__ = [
@@ -273,6 +273,7 @@ def replan(topo: Topology, traffic: np.ndarray, channel_bw: np.ndarray,
     Returns (table, nrank_result).  ``table.unroutable`` flags pairs no
     dimension order can serve; shed their generation upstream.
     """
+    tracer = tracer if tracer is not None else NULL_TRACER
     bw = np.asarray(channel_bw, np.float64)
     down = np.nonzero(bw <= 0)[0]
     plan_topo = dataclasses.replace(topo, channel_bw=bw)
@@ -293,8 +294,9 @@ def replan(topo: Topology, traffic: np.ndarray, channel_bw: np.ndarray,
         table = bidor(plan_topo, nr.w_nr,
                       down_channels=down if down.size else None)
     if greedy_sweeps > 0:
-        table = greedy_refine(plan_topo, traffic, table,
-                              sweeps=greedy_sweeps)
+        with span(tracer, "greedy_refine", cat="ctrl") as a:
+            table = greedy_refine(plan_topo, traffic, table,
+                                  sweeps=greedy_sweeps, stats=a)
     # deadlock gate on the hot-swap artifact: build_plan_fast certifies
     # its own output, but greedy refinement (and the host-oracle path)
     # re-shape the choice table afterwards — certify what actually ships
@@ -391,6 +393,8 @@ def _bw_slots(bw_hist, epoch: int, slots: int, total: int) -> np.ndarray:
 
 
 _NR_FIELDS = ("w_nr", "w0", "w_final", "p", "p_drn", "w_possibility")
+# the sim counters the controller reads at every epoch boundary
+_COUNTERS = ("next_seq", "chan_seen", "chan_fwd", "meas_cnt")
 
 
 def _ctrl_snapshot(batched, *, bound_i, sat, link_peak, bw, cur_traffic,
@@ -468,11 +472,13 @@ def run_controlled(topo: Topology, traffic: np.ndarray, cfg: SimConfig,
     and its results are bit-identical to the uninterrupted run
     (``tests/test_service.py``).
 
-    ``tracer`` — optional :class:`repro.obs.trace.TraceWriter`; when
-    present the loop emits ctrl-plane events (epoch spans, drift scores,
-    detection firings, environment events, replan spans, table
-    hot-swaps).  Epoch spans block on device completion to time real
-    work, so tracing perturbs wall time but never results.
+    ``tracer`` — optional tracer (:mod:`repro.obs.trace`, whose
+    docstring lists the spans); when present the loop emits ctrl-plane
+    events (epoch and boundary spans, drift scores, detection firings,
+    environment events, replan spans with their planner, BiDOR-G,
+    certifier and hot-swap children).  Epoch spans block on device
+    completion to time real work, so tracing perturbs wall time but
+    never results.
     """
     tracer = tracer if tracer is not None else NULL_TRACER
     log = EventLog(verbose=verbose)
@@ -487,7 +493,7 @@ def run_controlled(topo: Topology, traffic: np.ndarray, cfg: SimConfig,
     nr_prev = nrank0   # seed plan's fixed point: first replan warm-starts
     if cfg.algo == Algo.BIDOR:
         if table is None:
-            plan0 = build_plan_fast(topo, traffic)
+            plan0 = build_plan_fast(topo, traffic, tracer=tracer)
             table, nr_prev = plan0.table, plan0.nrank
     tables, meta = build_tables(
         topo, traffic, table if cfg.algo == Algo.BIDOR else None,
@@ -585,6 +591,7 @@ def run_controlled(topo: Topology, traffic: np.ndarray, cfg: SimConfig,
             t_prev = bounds[j]
 
     t0 = bounds[resume_i - 1] if resume_i else 0
+    attempt = len(replans)   # replan ordinal in the session (trace args)
     for bound_i in range(resume_i, len(bounds)):
         t1 = bounds[bound_i]
         if checkpoint is not None and bound_i > resume_i:
@@ -595,167 +602,183 @@ def run_controlled(topo: Topology, traffic: np.ndarray, cfg: SimConfig,
                 fault_pending=fault_pending, estimator=estimator,
                 detector=detector, replans=replans, table=table,
                 nr_prev=nr_prev, bw_hist=bw_hist))
-        runner = get_runner(meta, cfg, t1 - t0, num_lanes=nlanes,
-                            multi_device=multi_device)
-        te0 = tracer.now_us() if tracer.enabled else 0.0
-        batched = runner(tables, batched)
-        if tracer.enabled:
-            # block so the span times the device work, not the dispatch
-            jax.block_until_ready(batched)
-            tracer.complete(
-                "epoch", te0, tracer.now_us() - te0, cat="sim",
-                args={"t0": t0, "t1": t1, "scenario": scenario.name,
-                      "policy": policy})
+        with span(tracer, "epoch", cat="sim", t0=t0, t1=t1,
+                  cycles=t1 - t0, scenario=scenario.name,
+                  policy=policy) as a:
+            builds = runner_builds()
+            runner = get_runner(meta, cfg, t1 - t0, num_lanes=nlanes,
+                                multi_device=multi_device)
+            a["compiled"] = runner_builds() > builds
+            batched = runner(tables, batched)
+            if tracer.enabled:
+                # block so the span times the device work, not the dispatch
+                jax.block_until_ready(batched)
         epoch_bounds.append((t0, t1))
         t0 = t1
 
-        # ---- read counters (one small host transfer) ---- #
-        seq = np.asarray(jax.device_get(batched["next_seq"]), np.int64)
-        seen = np.asarray(jax.device_get(batched["chan_seen"]), np.int64)
-        fwd = np.asarray(jax.device_get(batched["chan_fwd"]), np.int64)
-        meas = np.asarray(jax.device_get(batched["meas_cnt"]), np.int64)
-        d_seq, d_seen = seq - prev_seq, seen - prev_seen
-        d_fwd, d_meas = fwd - prev_fwd, meas - prev_meas
-        prev_seq, prev_seen, prev_fwd, prev_meas = seq, seen, fwd, meas
+        with span(tracer, "boundary", cat="ctrl", cycle=t1) as a:
+            # ---- read counters (one small host transfer) ---- #
+            seq, seen, fwd, meas = (
+                np.asarray(jax.device_get(batched[k]), np.int64)
+                for k in _COUNTERS)
+            host_bytes = sum(batched[k].nbytes for k in _COUNTERS)
+            d_seq, d_seen = seq - prev_seq, seen - prev_seen
+            d_fwd, d_meas = fwd - prev_fwd, meas - prev_meas
+            prev_seq, prev_seen, prev_fwd, prev_meas = seq, seen, fwd, meas
 
-        # time-resolved max normalized link load (this epoch's bw)
-        live = bw > 0
-        for i in range(nlanes):
-            if d_meas[i] > 0 and live.any():
-                loads = d_fwd[i, live] / float(d_meas[i]) / bw[live]
-                link_peak[i] = max(link_peak[i], float(loads.max()))
+            # time-resolved max normalized link load (this epoch's bw)
+            live = bw > 0
+            for i in range(nlanes):
+                if d_meas[i] > 0 and live.any():
+                    loads = d_fwd[i, live] / float(d_meas[i]) / bw[live]
+                    link_peak[i] = max(link_peak[i], float(loads.max()))
 
-        if t1 > cfg.warmup:
-            # saturation accumulates from post-warmup reads only — a
-            # transient warmup spike must not permanently latch a lane
-            sat |= queue_occupancy(tables, cfg, batched["q_size"],
-                                   q_meta) >= sat_th
+            if t1 > cfg.warmup:
+                # saturation accumulates from post-warmup reads only — a
+                # transient warmup spike must not permanently latch a lane
+                sat |= queue_occupancy(tables, cfg, batched["q_size"],
+                                       q_meta) >= sat_th
+                host_bytes += batched["q_size"].nbytes
 
-        estimator.update(d_seq.sum(axis=0))
-        drifted = detector.update(d_seen.sum(axis=0))
-        if tracer.enabled:
-            tracer.counter("drift_tv", {"tv": detector.last_distance},
-                           cat="ctrl")
-            if drifted:
-                tracer.instant(
-                    "drift_detected", cat="ctrl",
-                    args={"cycle": t1, "tv": detector.last_distance})
-
-        if t1 >= total:
-            break
-
-        # ---- apply due events (the environment) ---- #
-        due = [e for e in scenario.events if e.cycle == t1]
-        event_kinds: set = set()
-        if due:
-            bw, new_traffic, rate_scale, event_kinds = _apply_events(
-                due, bw, topo, base_bw)
+            estimator.update(d_seq.sum(axis=0))
+            drifted = detector.update(d_seen.sum(axis=0))
             if tracer.enabled:
-                for ev in due:
-                    a = {"cycle": t1}
-                    if isinstance(ev, LinkFail):
-                        a["bw_scale"] = ev.bw_scale
-                    tracer.instant(type(ev).__name__, cat="env", args=a)
-            if "fault" in event_kinds:
-                bw_hist.append((t1, bw.copy()))
-            gen_traffic = new_traffic
-            if new_traffic is not None and cur_unroutable is not None:
-                # an active shed outlives a traffic epoch: the dead link
-                # is still dead, so the new matrix generates under the
-                # same admission-control mask until the next replan
-                gen_traffic = np.where(cur_unroutable, 0.0, new_traffic)
-            tables = retarget_tables(
-                tables, topo,
-                traffic=gen_traffic,
-                channel_bw=bw if "fault" in event_kinds else None)
-            if gen_traffic is not None:
-                cur_gen = gen_traffic
-                q_meta = source_queue_meta(tables, cfg)
-            if new_traffic is not None:
-                cur_traffic = new_traffic
-            if rate_scale is not None:
-                # absolute vs base: rate_scale=1.0 restores the original
-                # injection rates after a previously scaled epoch
-                batched["rate"] = jnp.asarray(
-                    [r * rate_scale for r, _ in points], jnp.float32)
-            fault_pending |= "fault" in event_kinds
+                tracer.counter("drift_tv", {"tv": detector.last_distance},
+                               cat="ctrl")
+                if drifted:
+                    tracer.instant(
+                        "drift_detected", cat="ctrl",
+                        args={"cycle": t1, "tv": detector.last_distance})
+                est = estimator.matrix
+                a["nonzero_pairs"] = (0 if est is None
+                                      else int(np.count_nonzero(est)))
 
-        # ---- control decision ---- #
-        if cfg.algo != Algo.BIDOR or policy == "stale":
-            continue
-        if policy == "oracle":
-            do, trigger, m = bool(due), "event", cur_traffic
-        else:  # online
-            # faults are signalled out of band (hardware link state, as in
-            # real fabrics); traffic drift must be *detected*
-            trigger = "fault" if fault_pending else "drift"
-            do = fault_pending or drifted
-            # estimator.matrix backs off to the offline prior until the
-            # first packets arrive, so a cold-start fault replans from
-            # the plan-time statistics; None only when there is no
-            # demand to plan for at all
-            m = estimator.matrix
-            if m is None:
-                do = False
+            if t1 >= total:
+                a["host_bytes"] = host_bytes
+                break
+
+            # ---- apply due events (the environment) ---- #
+            due = [e for e in scenario.events if e.cycle == t1]
+            event_kinds: set = set()
+            if due:
+                bw, new_traffic, rate_scale, event_kinds = _apply_events(
+                    due, bw, topo, base_bw)
+                if tracer.enabled:
+                    for ev in due:
+                        ea = {"cycle": t1}
+                        if isinstance(ev, LinkFail):
+                            ea["bw_scale"] = ev.bw_scale
+                        tracer.instant(type(ev).__name__, cat="env",
+                                       args=ea)
+                if "fault" in event_kinds:
+                    bw_hist.append((t1, bw.copy()))
+                gen_traffic = new_traffic
+                if new_traffic is not None and cur_unroutable is not None:
+                    # an active shed outlives a traffic epoch: the dead
+                    # link is still dead, so the new matrix generates
+                    # under the same admission-control mask until the
+                    # next replan
+                    gen_traffic = np.where(cur_unroutable, 0.0, new_traffic)
+                tables = retarget_tables(
+                    tables, topo,
+                    traffic=gen_traffic,
+                    channel_bw=bw if "fault" in event_kinds else None)
+                if gen_traffic is not None:
+                    cur_gen = gen_traffic
+                    q_meta = source_queue_meta(tables, cfg)
+                    host_bytes += tables.p_gen.nbytes
+                if new_traffic is not None:
+                    cur_traffic = new_traffic
+                if rate_scale is not None:
+                    # absolute vs base: rate_scale=1.0 restores the
+                    # original injection rates after a previously scaled
+                    # epoch
+                    batched["rate"] = jnp.asarray(
+                        [r * rate_scale for r, _ in points], jnp.float32)
+                fault_pending |= "fault" in event_kinds
+            a["host_bytes"] = host_bytes
+
+            # ---- control decision ---- #
+            do = False
+            if cfg.algo == Algo.BIDOR and policy == "oracle":
+                do, trigger, m = bool(due), "event", cur_traffic
+            elif cfg.algo == Algo.BIDOR and policy == "online":
+                # faults are signalled out of band (hardware link state,
+                # as in real fabrics); traffic drift must be *detected*
+                trigger = "fault" if fault_pending else "drift"
+                # estimator.matrix backs off to the offline prior until
+                # the first packets arrive, so a cold-start fault replans
+                # from the plan-time statistics; None only when there is
+                # no demand to plan for at all
+                m = estimator.matrix
+                do = (fault_pending or drifted) and m is not None
         if not do:
             continue
         drift_dist = detector.last_distance
-        tr0 = tracer.now_us() if tracer.enabled else 0.0
-        table, nr_prev = replan(
-            topo, m, bw, nr_prev,
-            warm=rc.warm, greedy_sweeps=rc.greedy_sweeps, tracer=tracer)
-        # hot-swap guard: a replan that sheds most of the demanded pairs
-        # would silently wedge the run behind a near-empty table — keep
-        # the previous (still-certified) table and record the rejection
-        if table.unroutable is not None:
-            demanded = np.asarray(cur_traffic) > 0
-            n_dem = int(demanded.sum())
-            shed_frac = (int((table.unroutable & demanded).sum()) / n_dem
-                         if n_dem else 0.0)
-            if shed_frac > rc.max_shed:
-                if tracer.enabled:
-                    tracer.instant(
-                        "hot_swap_rejected", cat="ctrl",
-                        args={"cycle": t1, "trigger": trigger,
-                              "shed_frac": round(shed_frac, 4),
-                              "max_shed": rc.max_shed})
+        rt = tagged(tracer, replan=attempt)
+        attempt += 1
+        with span(rt, "replan", cat="ctrl", cycle=t1,
+                  trigger=trigger) as ra:
+            table, nr_prev = replan(
+                topo, m, bw, nr_prev,
+                warm=rc.warm, greedy_sweeps=rc.greedy_sweeps, tracer=rt)
+            with span(rt, "hot_swap", cat="ctrl", cycle=t1) as ha:
+                # hot-swap guard: a replan that sheds most of the demanded
+                # pairs would silently wedge the run behind a near-empty
+                # table — keep the previous (still-certified) table and
+                # record the rejection
+                shed, n_dem = 0, 0
+                if table.unroutable is not None:
+                    demanded = np.asarray(cur_traffic) > 0
+                    n_dem = int(demanded.sum())
+                    shed = int((table.unroutable & demanded).sum())
+                shed_frac = shed / n_dem if n_dem else 0.0
+                rejected = shed_frac > rc.max_shed
+                ha.update(shed_pairs=shed, rejected=rejected)
+                if not rejected:
+                    # admission control: shed unroutable pairs from
+                    # generation; when the new plan can serve everything
+                    # (e.g. after LinkRecover), restore the full current
+                    # matrix — a previous shed must not outlive the fault
+                    # that caused it
+                    gen = cur_traffic
+                    cur_unroutable = None
+                    if (table.unroutable is not None
+                            and table.unroutable.any()):
+                        cur_unroutable = table.unroutable
+                        gen = np.where(cur_unroutable, 0.0, cur_traffic)
+                    tables = retarget_tables(tables, topo,
+                                             choice=table.choice,
+                                             traffic=gen)
+                    cur_gen = gen
+                    q_meta = source_queue_meta(tables, cfg)
+            detector.reset()
+            fault_pending = False
+            if rejected:
+                ra.drop()
+                rt.instant(
+                    "hot_swap_rejected", cat="ctrl",
+                    args={"cycle": t1, "trigger": trigger,
+                          "shed_frac": round(shed_frac, 4),
+                          "max_shed": rc.max_shed})
                 log.event("replan_rejected",
                           f"ctrl[{scenario.name}/{policy}] hot-swap "
                           f"rejected @ {t1}: shed {shed_frac:.0%} > "
                           f"max {rc.max_shed:.0%}", cycle=t1,
                           trigger=trigger)
-                detector.reset()
-                fault_pending = False
-                continue
-        # admission control: shed unroutable pairs from generation; when
-        # the new plan can serve everything (e.g. after LinkRecover),
-        # restore the full current matrix — a previous shed must not
-        # outlive the fault that caused it
-        gen = cur_traffic
-        cur_unroutable = None
-        if table.unroutable is not None and table.unroutable.any():
-            cur_unroutable = table.unroutable
-            gen = np.where(cur_unroutable, 0.0, cur_traffic)
-        tables = retarget_tables(tables, topo, choice=table.choice,
-                                 traffic=gen)
-        cur_gen = gen
-        q_meta = source_queue_meta(tables, cfg)
-        detector.reset()
-        fault_pending = False
-        replans.append(Replan(
-            cycle=t1, trigger=trigger, iterations=nr_prev.iterations,
-            unroutable_pairs=int(table.unroutable.sum())
-            if table.unroutable is not None else 0,
-            drift_distance=drift_dist))
-        if tracer.enabled:
-            tracer.complete(
-                "replan", tr0, tracer.now_us() - tr0, cat="ctrl",
-                args={"cycle": t1, "trigger": trigger,
-                      "warm": rc.warm and nr_prev is not None,
-                      "iterations": int(nr_prev.iterations),
-                      "unroutable": replans[-1].unroutable_pairs,
-                      "drift_tv": drift_dist})
-            tracer.instant("hot_swap", cat="ctrl", args={"cycle": t1})
+            else:
+                replans.append(Replan(
+                    cycle=t1, trigger=trigger,
+                    iterations=nr_prev.iterations,
+                    unroutable_pairs=int(table.unroutable.sum())
+                    if table.unroutable is not None else 0,
+                    drift_distance=drift_dist))
+                ra.update(warm=rc.warm and nr_prev is not None,
+                          iterations=int(nr_prev.iterations),
+                          unroutable=replans[-1].unroutable_pairs,
+                          drift_tv=drift_dist)
+        if rejected:
+            continue
         log.event("replan",
                   f"ctrl[{scenario.name}/{policy}] replan @ {t1} "
                   f"({trigger}), {nr_prev.iterations} iters",

@@ -54,7 +54,7 @@ import numpy as np
 
 from repro.core.plan_cache import PlanCache, topology_fingerprint
 from repro.obs.probe import Telemetry
-from repro.obs.trace import NULL_TRACER, TraceWriter
+from repro.obs.trace import NULL_TRACER, TraceWriter, clock_us, span, tagged
 from .campaign import (CampaignExecutor, CampaignPoint, CampaignResult,
                        CampaignSpec, CellKey, CellOutcome, campaign_cells,
                        csv_rows)
@@ -319,6 +319,10 @@ class CampaignJob:
     (default — ``<root>/plan-cache``, shared by every job under the
     root), or None to disable plan caching.
 
+    ``trace=True`` streams the job's spans and events to
+    ``<job>/trace.jsonl``; ``tracer`` hands them to any object with the
+    tracer interface (:mod:`repro.obs.trace`) instead.
+
     **Chaos hardening.**  Every stored cell npz carries a sha256
     sidecar; a cached cell that fails verification (or fails to parse)
     is moved to ``cells/quarantine/`` and recomputed — corruption costs
@@ -337,41 +341,54 @@ class CampaignJob:
                  resume: bool = True,
                  verbose: bool = False,
                  trace: bool = False,
+                 tracer=None,
                  max_retries: int = 2,
                  retry_backoff_s: float = 0.5):
-        self.spec = spec
-        self.fingerprint = spec_fingerprint(spec)
-        self.job_id = job_id or f"job-{self.fingerprint[:12]}"
-        self.dir = os.path.join(root, self.job_id)
-        self.cells_dir = os.path.join(self.dir, "cells")
-        self.quarantine_dir = os.path.join(self.cells_dir, "quarantine")
-        self.ckpt_dir = os.path.join(self.dir, "ckpt")
-        self.csv_path = os.path.join(self.dir, "results.csv")
-        self.metrics_path = os.path.join(self.dir, "metrics.jsonl")
-        self.trace_path = os.path.join(self.dir, "trace.jsonl")
-        self.verbose = verbose
-        self.max_retries = int(max_retries)
-        self.retry_backoff_s = float(retry_backoff_s)
-        if plan_cache == "shared":
-            plan_cache = PlanCache(os.path.join(root, "plan-cache"))
-        elif isinstance(plan_cache, str):
-            plan_cache = PlanCache(plan_cache)
-        self.plan_cache = plan_cache
-        self.cells = campaign_cells(spec)
+        if trace and tracer is not None:
+            raise ValueError("pass trace=True or a tracer, not both")
+        # job_open times the job's set-up up to its manifest, and reaches
+        # the tracer once that exists: the trace file opens after
+        # _init_manifest, whose resume=False wipe must not unlink it out
+        # from under an open writer
+        clock = tracer.now_us if tracer is not None else clock_us
+        t_open = clock()
+        with span(NULL_TRACER, "job_open"):    # the profiler's annotation
+            self.spec = spec
+            self.fingerprint = spec_fingerprint(spec)
+            self.job_id = job_id or f"job-{self.fingerprint[:12]}"
+            self.dir = os.path.join(root, self.job_id)
+            self.cells_dir = os.path.join(self.dir, "cells")
+            self.quarantine_dir = os.path.join(self.cells_dir, "quarantine")
+            self.ckpt_dir = os.path.join(self.dir, "ckpt")
+            self.csv_path = os.path.join(self.dir, "results.csv")
+            self.metrics_path = os.path.join(self.dir, "metrics.jsonl")
+            self.trace_path = os.path.join(self.dir, "trace.jsonl")
+            self.verbose = verbose
+            self.max_retries = int(max_retries)
+            self.retry_backoff_s = float(retry_backoff_s)
+            if plan_cache == "shared":
+                plan_cache = PlanCache(os.path.join(root, "plan-cache"))
+            elif isinstance(plan_cache, str):
+                plan_cache = PlanCache(plan_cache)
+            self.plan_cache = plan_cache
+            self.cells = campaign_cells(spec)
+            os.makedirs(self.cells_dir, exist_ok=True)
+            os.makedirs(self.quarantine_dir, exist_ok=True)
+            os.makedirs(self.ckpt_dir, exist_ok=True)
+            self._init_manifest(resume)
+        dur = clock() - t_open
         # progress shared with status(): guarded so a concurrent reader
         # never sees a torn (done, in_flight, walls) triple
         self._lock = threading.Lock()
         self._in_flight: str | None = None
         self._done: int | None = None    # None ⇔ no run() in this process
         self._walls: list[float] = []    # executed-cell walls (ETA basis)
-        os.makedirs(self.cells_dir, exist_ok=True)
-        os.makedirs(self.quarantine_dir, exist_ok=True)
-        os.makedirs(self.ckpt_dir, exist_ok=True)
-        self._init_manifest(resume)
-        # after _init_manifest: a resume=False wipe must not unlink the
-        # trace file out from under an already-open writer
-        self.tracer = (TraceWriter(self.trace_path) if trace
-                       else NULL_TRACER)
+        if trace:
+            tracer = TraceWriter(self.trace_path)
+        self.tracer = tracer if tracer is not None else NULL_TRACER
+        if self.tracer.enabled:
+            self.tracer.complete("job_open", t_open, dur, cat="service",
+                                 args={"job": self.job_id})
         self.executor = CampaignExecutor(
             spec, bidor_tables=bidor_tables, plan_cache=plan_cache,
             verbose=verbose, tracer=self.tracer)
@@ -603,10 +620,12 @@ class CampaignJob:
                     with self._lock:
                         self._in_flight = None
                     continue
-                _save_outcome(path, outcome)
-                if outcome.telemetry is not None:
-                    outcome.telemetry.save(self._tel_path(key))
-                ckpt.clear()
+                with span(tagged(self.tracer, slug=key.slug), "cell_save",
+                          cat="service"):
+                    _save_outcome(path, outcome)
+                    if outcome.telemetry is not None:
+                        outcome.telemetry.save(self._tel_path(key))
+                    ckpt.clear()
                 executed += 1
                 with self._lock:
                     self._in_flight = None
@@ -682,15 +701,18 @@ def run_campaign_service(spec: CampaignSpec, *, root: str = DEFAULT_ROOT,
                          resume: bool = True,
                          max_cells: int | None = None,
                          verbose: bool = False,
-                         trace: bool = False):
+                         trace: bool = False,
+                         tracer=None):
     """Run (or resume) a campaign job to completion and return its
     :class:`CampaignResult`; with ``max_cells`` set the job may stop
     early, returning ``(None, job)`` — callers re-invoke to continue.
+    ``trace`` and ``tracer`` as in :class:`CampaignJob`.
 
     Returns ``(result | None, job)``.
     """
     job = CampaignJob(spec, root=root, job_id=job_id,
                       bidor_tables=bidor_tables, plan_cache=plan_cache,
-                      resume=resume, verbose=verbose, trace=trace)
+                      resume=resume, verbose=verbose, trace=trace,
+                      tracer=tracer)
     complete = job.run(max_cells)
     return (job.result() if complete else None), job

@@ -833,6 +833,14 @@ def get_runner(meta: dict, cfg: SimConfig, num_cycles: int, *,
                        int(num_cycles))
 
 
+def runner_builds() -> int:
+    """Runners :func:`get_runner` has built in this process (its caches'
+    misses).  A call that raises the count built a runner, which
+    compiles on its first call."""
+    return (_get_runner.cache_info().misses
+            + _get_sharded_runner.cache_info().misses)
+
+
 def hist_percentile(hist: np.ndarray, bin_width: int, q: float) -> float:
     """q-quantile (0 < q < 1) from a fixed-width latency histogram, with
     linear interpolation inside the bin.  The last bin is an overflow

@@ -6,7 +6,8 @@ Three planes of observability over the NoC stack:
   inside the jitted chunk scan (off by default, bit-identical when
   off);
 * :mod:`repro.obs.trace` — Chrome trace-event streaming for ctrl-plane
-  events and host-side spans (Perfetto-viewable), plus
+  events and host-side spans (Perfetto-viewable; each span is also a
+  profiler annotation, on the device trace's clock), plus
   :mod:`repro.obs.log`'s structured event log behind the ``verbose=``
   flags;
 * :mod:`repro.obs.report` — per-job report rendering (trajectories,

@@ -23,7 +23,64 @@ jobs land on one coherent timeline.
 
 :data:`NULL_TRACER` is the no-op sink: instrumented code paths take a
 ``tracer`` and default to it, so tracing off costs one attribute call
-per event site and nothing else.
+per event site and nothing else.  Any object with the same methods
+(``enabled``, ``now_us``, ``complete``, ``instant``, ``counter``) is a
+tracer.
+
+The program's spans go through :func:`span`, which also opens a
+``jax.profiler.TraceAnnotation`` of the span's name: under an active
+profiler every span appears on the profile's host plane, on the clock
+of the device ops.  :func:`tagged` adds fixed args to every event of a
+tracer, so that the spans of one replan share its ``replan`` ordinal
+and those of one campaign cell its ``slug``.
+
+Span vocabulary (children nest inside their parent's interval):
+
+=======================  ===================  =================================
+span                     parent               args
+=======================  ===================  =================================
+``job_open``             —                    ``job``: spec fingerprint, plan
+                                              cache, manifest (recorded once
+                                              the tracer exists, with its
+                                              measured start)
+``prep_topo``            —                    ``slug``, ``cached`` (every plan
+                                              from the plan cache or memory)
+``cell``                 —                    ``slug``, ``topo``, ``pattern``,
+                                              ``algo``, ``scenario``, ``lanes``
+``chunk``                ``cell``             ``cycles``, ``compiled`` (a new
+                                              runner was built for the length)
+``cell_save``            —                    ``slug``: npz, sidecar, telemetry
+``epoch``                ``cell`` or —        ``t0``, ``t1``, ``cycles``,
+                                              ``compiled``, ``scenario``,
+                                              ``policy``
+``boundary``             ``cell`` or —        ``cycle``, ``host_bytes``,
+                                              ``nonzero_pairs``: counters read,
+                                              estimator, detector, due events,
+                                              control decision
+``replan``               ``cell`` or —        ``replan``, ``cycle``,
+                                              ``trigger``, ``warm``,
+                                              ``iterations``, ``unroutable``,
+                                              ``drift_tv``; left out when the
+                                              hot-swap guard rejects the plan
+``build_plan_fast``      ``replan`` or —      ``nodes``, ``warm``, ``faults``
+``plan_statics``         ``build_plan_fast``  ``faults``
+``plan_device``          ``build_plan_fast``  ``warm``: inputs to the device,
+                                              the jitted plan, results back
+``plan_assemble``        ``build_plan_fast``  —
+``certify``              a gate, ``replan``   ``label``, ``verdict``, CDG
+                                              sizes, ``wall_ms``
+``greedy_refine``        ``replan``           ``pairs``, ``sweeps_run``,
+                                              ``changed``
+``hot_swap``             ``replan``           ``cycle``, ``shed_pairs``,
+                                              ``rejected``: shed guard,
+                                              admission control, table retarget
+``build_plans_batched``  —                    ``nodes``, ``lanes``, ``faults``
+=======================  ===================  =================================
+
+Instants: ``drift_detected``, ``LinkFail`` / ``LinkRecover`` /
+``TrafficDrift``, ``hot_swap_rejected``, ``plan_cache_hit`` /
+``plan_cache_miss``, ``watchdog_tripped`` and the event log's kinds.
+Counter: ``drift_tv``.
 """
 
 from __future__ import annotations
@@ -34,8 +91,13 @@ import os
 import threading
 import time
 
-__all__ = ["TraceWriter", "NullTracer", "NULL_TRACER", "read_trace",
-           "validate_events"]
+__all__ = ["TraceWriter", "NullTracer", "NULL_TRACER", "SpanArgs", "span",
+           "tagged", "clock_us", "read_trace", "validate_events"]
+
+
+def clock_us() -> float:
+    """The trace clock: microseconds since the Unix epoch."""
+    return time.time() * 1e6
 
 
 class NullTracer:
@@ -55,10 +117,6 @@ class NullTracer:
     def complete(self, name, ts_us, dur_us, **kw) -> None:
         pass
 
-    @contextlib.contextmanager
-    def span(self, name, **kw):
-        yield {}
-
     def flush(self) -> None:
         pass
 
@@ -67,6 +125,80 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
+
+
+class SpanArgs(dict):
+    """The args of an open :func:`span`: the body adds the counts it
+    finds, and :meth:`drop` leaves the span out of the tracer."""
+
+    dropped = False
+
+    def drop(self) -> None:
+        self.dropped = True
+
+
+@contextlib.contextmanager
+def span(tracer, name: str, *, cat: str = "host", **args):
+    """``with span(tracer, "replan", cycle=t) as a:`` — one complete
+    event of the body's host wall time, and a profiler annotation of the
+    same name around it.
+
+    Yields a :class:`SpanArgs` holding ``args``; what the body adds to it
+    reaches ``tracer.complete``, which is the only tracer method called
+    (exceptions are flagged ``error`` and re-raised).  The span blocks on
+    nothing: device time is the profile's to tell."""
+    from jax.profiler import TraceAnnotation   # the report renders without JAX
+
+    a = SpanArgs(args)
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    t0 = tracer.now_us()
+    try:
+        yield a
+    except BaseException:
+        a["error"] = True
+        raise
+    finally:
+        dur = tracer.now_us() - t0
+        ann.__exit__(None, None, None)
+        if tracer.enabled and not a.dropped:
+            tracer.complete(name, t0, dur, cat=cat, args=dict(a) or None)
+
+
+class _Tagged:
+    """A tracer that adds fixed args to the spans and instants of
+    another (see :func:`tagged`)."""
+
+    enabled = True
+
+    def __init__(self, inner, args: dict):
+        self._inner, self._args = inner, args
+
+    def now_us(self) -> float:
+        return self._inner.now_us()
+
+    def complete(self, name, ts_us, dur_us, *, args=None, **kw) -> None:
+        self._inner.complete(name, ts_us, dur_us,
+                             args={**self._args, **(args or {})}, **kw)
+
+    def instant(self, name, *, args=None, **kw) -> None:
+        self._inner.instant(name, args={**self._args, **(args or {})},
+                            **kw)
+
+    def counter(self, name, values, **kw) -> None:
+        self._inner.counter(name, values, **kw)
+
+    def flush(self) -> None:
+        self._inner.flush()
+
+
+def tagged(tracer, **args):
+    """``tracer`` with ``args`` added to each of its spans and instants
+    (a span's own args win); :data:`NULL_TRACER` for an absent or
+    disabled tracer."""
+    if tracer is None or not tracer.enabled:
+        return NULL_TRACER
+    return _Tagged(tracer, args)
 
 
 class TraceWriter:
@@ -96,7 +228,7 @@ class TraceWriter:
 
     def now_us(self) -> float:
         """Current timestamp on the trace clock (Unix epoch µs)."""
-        return time.time() * 1e6
+        return clock_us()
 
     # ------------------------------------------------------------- #
     def _emit(self, ev: dict) -> None:
@@ -131,23 +263,6 @@ class TraceWriter:
         if args:
             ev["args"] = args
         self._emit(ev)
-
-    @contextlib.contextmanager
-    def span(self, name: str, *, cat: str = "host",
-             args: dict | None = None, tid: int = 0):
-        """``with tracer.span("replan") as a:`` — emits one complete
-        event on exit (exceptions included, flagged in args).  The
-        yielded dict collects extra args discovered inside the span."""
-        extra: dict = {}
-        t0 = self.now_us()
-        try:
-            yield extra
-        except BaseException:
-            extra["error"] = True
-            raise
-        finally:
-            self.complete(name, t0, self.now_us() - t0, cat=cat,
-                          args={**(args or {}), **extra} or None, tid=tid)
 
     def flush(self) -> None:
         with self._lock:

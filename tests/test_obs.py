@@ -16,6 +16,7 @@ from repro.noc.sim import (build_tables, fresh_state,
 from repro.obs import (EventLog, TEL_COUNT_FIELDS, TEL_KEYS, Telemetry,
                        TraceWriter, read_trace, resolved_epoch,
                        telemetry_state, validate_events)
+from repro.obs.trace import span
 
 TOPO = mesh2d(3, 3)
 UNI = traffic.uniform(TOPO)
@@ -38,10 +39,10 @@ def test_trace_writer_roundtrip_schema_and_kill_safety(tmp_path):
     w.counter("drift_tv", {"tv": 0.12}, cat="ctrl")
     t0 = w.now_us()
     w.complete("replan", t0, 1234.5, cat="ctrl", args={"trigger": "fault"})
-    with w.span("build", cat="plan", args={"nodes": 9}):
+    with span(w, "build", cat="plan", nodes=9):
         pass
     with pytest.raises(RuntimeError):
-        with w.span("boom", cat="plan"):
+        with span(w, "boom", cat="plan"):
             raise RuntimeError("x")
     # NO close(): the stream must parse as written (kill safety)
     events = read_trace(path)

@@ -4,6 +4,7 @@ accessors the service streams into."""
 
 import copy
 import json
+import os
 
 import numpy as np
 import pytest
@@ -490,3 +491,67 @@ def test_job_trace_records_cells_and_is_perfetto_parseable(tmp_path):
     assert "build_plans_batched" in names
     slugs = {e["args"]["slug"] for e in events if e["name"] == "cell"}
     assert slugs == {k.slug for k in job.cells}
+
+
+class _SpanList:
+    """A tracer kept in memory: the complete spans it was handed."""
+
+    enabled = True
+
+    def __init__(self):
+        self.spans = []
+
+    def now_us(self):
+        import time
+        return time.time() * 1e6
+
+    def complete(self, name, ts_us, dur_us, *, args=None, **kw):
+        self.spans.append(dict(name=name, ts=ts_us, dur=dur_us,
+                               args=args or {}))
+
+    def instant(self, name, **kw):
+        pass
+
+    def counter(self, name, values, **kw):
+        pass
+
+    def flush(self):
+        pass
+
+
+def test_service_takes_a_tracer_from_outside(tmp_path):
+    spec = _spec(scenarios=(), chunk=400)
+    with pytest.raises(ValueError):
+        CampaignJob(spec, root=str(tmp_path), job_id="both", trace=True,
+                    tracer=_SpanList())
+    tr = _SpanList()
+    res, job = run_campaign_service(spec, root=str(tmp_path), job_id="out",
+                                    tracer=tr)
+    assert res is not None and not os.path.exists(job.trace_path)
+    by = {}
+    for s in tr.spans:
+        by.setdefault(s["name"], []).append(s)
+    (opened,) = by["job_open"]
+    assert opened["args"] == {"job": "out"} and opened["dur"] >= 0
+    slugs = [k.slug for k in job.cells]
+    for name in ("prep_topo", "cell", "cell_save"):
+        assert [s["args"]["slug"] for s in by[name]] == slugs
+    # the first cell builds the topology's plans, the second finds them
+    assert [s["args"]["cached"] for s in by["prep_topo"]] == [False, True]
+    for prep, cell, save in zip(by["prep_topo"], by["cell"],
+                                by["cell_save"]):
+        assert opened["ts"] + opened["dur"] <= prep["ts"]
+        assert prep["ts"] + prep["dur"] <= cell["ts"]
+        assert cell["ts"] + cell["dur"] <= save["ts"]
+        # 1200 cycles in chunks of 400, inside the cell
+        chunks = [c for c in by["chunk"]
+                  if c["args"]["slug"] == cell["args"]["slug"]]
+        assert [c["args"]["cycles"] for c in chunks] == [400] * 3
+        assert all(cell["ts"] <= c["ts"] and c["ts"] + c["dur"]
+                   <= cell["ts"] + cell["dur"] for c in chunks)
+    # a second job reads the plans from the shared plan cache
+    again = _SpanList()
+    run_campaign_service(spec, root=str(tmp_path), job_id="again",
+                         tracer=again)
+    assert [s["args"]["cached"] for s in again.spans
+            if s["name"] == "prep_topo"] == [True, True]
