@@ -11,12 +11,16 @@ k-dimensional topologies (used for the multi-pod ICI fabric); with
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
+from operator import itemgetter
 
 import numpy as np
 
 from .topology import Topology
-from .routes import dimension_orders, route_costs, next_port_table
+from .routes import (dimension_orders, next_port_table, route_costs,
+                     route_links)
 
 __all__ = ["BiDORTable", "bidor", "bidor_k", "dor_table", "TIE_TOL"]
 
@@ -181,6 +185,89 @@ def bidor(topo: Topology, w_nr: np.ndarray,
                    down_channels=down_channels)
 
 
+# Route structure of the last fabric BiDOR-G refined, keyed on what DOR
+# walks depend on (orders, coordinates, channels): successive replans of
+# one fabric change traffic and bandwidth, never the walks.
+_ROUTES: dict = {}
+
+
+@dataclasses.dataclass(frozen=True)
+class _Routes:
+    """Traffic-free route data of every pair (row ``s * N + d``); the
+    arrays are read-only."""
+
+    links: list       # per order, (N*N, L) int32 channel ids; −1 = none
+    hops: list        # per order, (N*N,) route lengths
+    # per (order, alternative): which of the alternative's links the
+    # order's route also uses, (N*N, L) bool; and whether a pair can move
+    # from the one to the other, (N*N,) bool: both routes lie inside the
+    # graph and their links differ
+    shared: dict
+    movable: dict
+
+
+def _route_structure(topo: Topology, orders) -> tuple[_Routes, bool]:
+    """:class:`_Routes` of ``topo``, and whether it came from the cache."""
+    key = (tuple(orders), topo.dims, topo.wrap, topo.coords.tobytes(),
+           topo.channels.tobytes())
+    routes = _ROUTES.get(key)
+    if routes is not None:
+        return routes, True
+    n2 = topo.num_nodes ** 2
+    links, hops = [], []
+    for o in orders:
+        lo, ho = route_links(topo, o)
+        links.append(lo.reshape(n2, -1))
+        hops.append(ho.reshape(n2))
+    on = [np.arange(lo.shape[1]) < ho[:, None] for lo, ho in zip(links, hops)]
+    inside = [~(m & (lo < 0)).any(1) for m, lo in zip(on, links)]
+    shared, movable = {}, {}
+    for o in range(len(orders)):
+        for a in range(len(orders)):
+            if a == o:
+                continue
+            sh = np.zeros(links[a].shape, dtype=bool)
+            for h in range(links[o].shape[1]):
+                sh |= (links[a] == links[o][:, h:h + 1]) & on[o][:, h:h + 1]
+            sh &= on[a]
+            same = (sh.sum(1) == hops[a]) & (hops[a] == hops[o])
+            shared[o, a] = sh
+            movable[o, a] = inside[o] & inside[a] & ~same
+    for arr in (*links, *hops, *shared.values(), *movable.values()):
+        arr.flags.writeable = False
+    routes = _Routes(links=links, hops=hops, shared=shared, movable=movable)
+    _ROUTES.clear()
+    _ROUTES[key] = routes
+    return routes, False
+
+
+def _rows(arr: np.ndarray, lens: np.ndarray) -> list[list]:
+    """The first ``lens[i]`` entries of each row of ``arr``, as lists."""
+    flat = arr[np.arange(arr.shape[1]) < lens[:, None]].tolist()
+    ends = np.cumsum(lens).tolist()
+    return [flat[a:b] for a, b in zip([0] + ends[:-1], ends)]
+
+
+def _getter(links: list) -> itemgetter:
+    """``itemgetter`` of ``links`` that returns a tuple even for one."""
+    return itemgetter(*links) if len(links) > 1 else itemgetter(*links, *links)
+
+
+@contextlib.contextmanager
+def _cyclic_gc_paused():
+    """Hold the cyclic garbage collector.  BiDOR-G builds hundreds of
+    thousands of small acyclic lists and tuples, which the collector
+    would otherwise rescan, with every object of the process, as they
+    pile up."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
 def greedy_refine(topo: Topology, traffic, table: BiDORTable,
                   sweeps: int = 4, *, stats: dict | None = None
                   ) -> BiDORTable:
@@ -189,89 +276,122 @@ def greedy_refine(topo: Topology, traffic, table: BiDORTable,
     BiDOR minimizes each pair's *own* path cost against the static w_NR
     field; it never sees the load its choice induces on others.  BiDOR-G
     post-processes the table: sweep pairs in decreasing traffic order and
-    flip a pair's dimension order whenever that lowers the current maximum
-    link load (recomputed incrementally).  Still fully offline/quasi-static
-    — the output is the same bitmap artifact.
+    flip a pair's dimension order whenever that lowers the peak link
+    load among the links it would use (loads kept incrementally).  Still
+    fully offline/quasi-static — the output is the same bitmap artifact.
+
+    The sweep is sequential — each decision reads the loads the pairs
+    before it left — and in float64.  What it reads per pair is prepared
+    with array ops, so the loop touches Python lists only: each route's
+    links, and for each alternative its links grouped by the load a move
+    adds to them (``t[s, d] / bw[c]``; 0 on links it shares with the
+    current route).  Within a group the peak is ``max(loads) + added``,
+    which equals the largest ``load + added`` exactly (rounding is
+    monotone).  A pair whose orders all walk the same links, or that has
+    a single order inside the graph, can never flip and is not swept.
 
     ``stats``, when given, receives the work counts: ``pairs`` (pairs
-    with traffic that each sweep visits), ``sweeps_run`` and ``changed``
-    (entries of the output that differ from the input table).
+    with traffic), ``visited`` (those of them each sweep visits: the
+    pairs that could flip), ``sweeps_run``, ``changed`` (entries of the
+    output that differ from the input table) and ``route_cache_hit``
+    (the route structure came from the previous call's topology).
     """
-    import numpy as _np
-    from .routes import walk_routes
-    from .qstar import link_load as _link_load
+    from .qstar import link_load
 
-    t = _np.asarray(traffic, dtype=_np.float64)
+    t = np.asarray(traffic, dtype=np.float64)
     n = topo.num_nodes
     orders = table.orders
-    seqs = [walk_routes(topo, o) for o in orders]
-    chan_lut = _np.full((n, n), -1, _np.int64)
-    chan_lut[topo.channels[:, 0], topo.channels[:, 1]] = _np.arange(
-        topo.num_channels)
-
-    def pair_links(oi, s, d):
-        """Channel ids of route (s, d) under order oi; None if the route
-        crosses a channel absent from the (possibly degraded) graph."""
-        seq = seqs[oi][s, d]
-        ids = []
-        for h in range(len(seq) - 1):
-            a, b = int(seq[h]), int(seq[h + 1])
-            if a == b:
-                break
-            c = int(chan_lut[a, b])
-            if c < 0:
-                return None
-            ids.append(c)
-        return ids
-
+    routes, hit = _route_structure(topo, orders)
     choice = table.choice.copy()
-    load = _link_load(topo, t,
-                      BiDORTable(choice=choice, orders=orders,
-                                 costs=table.costs,
-                                 port_tables=table.port_tables,
-                                 unroutable=table.unroutable))
-    bw = _np.where(topo.channel_bw > 0, topo.channel_bw, 1e-12)
-    unroutable = table.unroutable
-    pairs = [(s, d) for s in range(n) for d in range(n)
-             if s != d and t[s, d] > 0
-             and not (unroutable is not None and unroutable[s, d])]
-    pairs.sort(key=lambda p: -t[p])
-    sweeps_run = 0
-    for _ in range(sweeps):
-        sweeps_run += 1
-        changed = 0
-        for s, d in pairs:
-            cur = int(choice[s, d])
-            cur_links = pair_links(cur, s, d)
-            if cur_links is None:
-                continue  # current route leaves the degraded graph
-            best_oi, best_peak = cur, max(
-                (load[c] for c in cur_links), default=0.0)
-            for oi in range(len(orders)):
-                if oi == cur:
+    load = link_load(topo, t, table,
+                     links=[lo.reshape(n, n, -1) for lo in routes.links])
+
+    # pairs with traffic in decreasing order (stable: row-major on ties),
+    # then those that could flip
+    cand = (t > 0) & ~np.eye(n, dtype=bool)
+    if table.unroutable is not None:
+        cand &= ~table.unroutable
+    idx = np.flatnonzero(cand)
+    idx = idx[np.argsort(-t.ravel()[idx], kind="stable")]
+    can_flip = np.zeros(idx.size, dtype=bool)
+    for m in routes.movable.values():
+        can_flip |= m[idx]
+    pairs, idx = idx.size, idx[can_flip]
+
+    bw = np.where(topo.channel_bw > 0, topo.channel_bw, 1e-12)
+    bwl = bw.tolist()
+    tp = t.ravel()[idx]
+    tl = tp.tolist()
+    with _cyclic_gc_paused():
+        lk = [lo[idx] for lo in routes.links]
+        hp = [h[idx] for h in routes.hops]
+        route = [_rows(lo, h) for lo, h in zip(lk, hp)]
+        peak_of = [[_getter(r) for r in ro] for ro in route]
+        on = [np.arange(lo.shape[1]) < h[:, None] for lo, h in zip(lk, hp)]
+        even = [((bw[lo] == bw[lo[:, :1]]) | ~m).all(1)
+                for lo, m in zip(lk, on)]
+        first = [(tp / bw[lo[:, 0]]).tolist() for lo in lk]
+        # moves[o][p]: the orders pair p can move to from order o, each
+        # with its links grouped by the load the move adds to them
+        moves = []
+        for o in range(len(orders)):
+            per_alt = []
+            for a in range(len(orders)):
+                if a == o:
                     continue
-                alt = pair_links(oi, s, d)
-                if alt is None:
+                ok = routes.movable[o, a][idx]
+                sh = routes.shared[o, a][idx]
+                # most moves add one load to every link: no link shared,
+                # one bandwidth along the route
+                plain = ok & even[a] & ~sh.any(1)
+                opts = [(a, ((g, x),)) if pl else None for pl, g, x in
+                        zip(plain.tolist(), peak_of[a], first[a])]
+                for p in np.flatnonzero(ok & ~plain).tolist():
+                    by = {}
+                    for c, s in zip(route[a][p], sh[p].tolist()):
+                        by.setdefault(0.0 if s else tl[p] / bwl[c],
+                                      []).append(c)
+                    opts[p] = (a, tuple((_getter(cs), x)
+                                        for x, cs in by.items()))
+                per_alt.append(opts)
+            moves.append([tuple(filter(None, m)) for m in zip(*per_alt)])
+
+        ld = load.tolist()
+        ch = choice.ravel()[idx].tolist()
+        sweeps_run = 0
+        for _ in range(sweeps):
+            sweeps_run += 1
+            changed = 0
+            for p, cur in enumerate(ch):
+                options = moves[cur][p]
+                if not options:
                     continue
-                # peak among affected links if we moved this pair
-                peak = 0.0
-                for c in alt:
-                    peak = max(peak, load[c]
-                               + (0 if c in cur_links else t[s, d] / bw[c]))
-                if peak < best_peak - 1e-15:
-                    best_oi, best_peak = oi, peak
-            if best_oi != cur:
-                for c in cur_links:
-                    load[c] -= t[s, d] / bw[c]
-                for c in pair_links(best_oi, s, d):
-                    load[c] += t[s, d] / bw[c]
-                choice[s, d] = best_oi
-                changed += 1
-        if changed == 0:
-            break
+                best_oi, best_peak = cur, max(peak_of[cur][p](ld))
+                for oi, groups in options:
+                    # peak among the links the pair would use if moved
+                    peak = 0.0
+                    for g, x in groups:
+                        v = max(g(ld)) + x
+                        if v > peak:
+                            peak = v
+                    if peak < best_peak - 1e-15:
+                        best_oi, best_peak = oi, peak
+                if best_oi != cur:
+                    x = tl[p]
+                    for c in route[cur][p]:
+                        ld[c] -= x / bwl[c]
+                    for c in route[best_oi][p]:
+                        ld[c] += x / bwl[c]
+                    ch[p] = best_oi
+                    changed += 1
+            if changed == 0:
+                break
+    choice.flat[idx] = ch
     if stats is not None:
-        stats.update(pairs=len(pairs), sweeps_run=sweeps_run,
-                     changed=int((choice != table.choice).sum()))
+        stats.update(pairs=pairs, visited=int(idx.size),
+                     sweeps_run=sweeps_run,
+                     changed=int((choice != table.choice).sum()),
+                     route_cache_hit=hit)
     return BiDORTable(choice=choice, orders=orders, costs=table.costs,
                       port_tables=table.port_tables,
                       unroutable=table.unroutable)

@@ -22,7 +22,7 @@ import numpy as np
 
 from .bidor import BiDORTable, bidor, bidor_k
 from .nrank import NRankResult, nrank, nrank_channel
-from .routes import walk_routes
+from .routes import route_links, walk_routes
 from .topology import Topology
 
 __all__ = ["QStarPlan", "build_plan", "predicted_node_load", "link_load",
@@ -129,32 +129,26 @@ def predicted_node_load(topo: Topology, traffic: np.ndarray,
 
 
 def link_load(topo: Topology, traffic: np.ndarray,
-              table: BiDORTable) -> np.ndarray:
+              table: BiDORTable, links: list | None = None) -> np.ndarray:
     """Per-channel load (bandwidth-normalized) implied by a routing table.
 
     Used to score ICI collective schedules: completion time of a decomposed
-    collective ∝ max link load.
+    collective ∝ max link load.  ``links``, when the caller has them, are
+    the :func:`repro.core.routes.route_links` arrays of ``table.orders``.
     """
     load = np.zeros(topo.num_channels, dtype=np.float64)
-    seqs = _route_seqs(topo, table.orders)
+    if links is None:
+        links = [route_links(topo, o)[0] for o in table.orders]
     t = np.asarray(traffic, dtype=np.float64)
     if table.unroutable is not None:
         t = np.where(table.unroutable, 0.0, t)  # shed traffic contributes 0
-    n = topo.num_nodes
-    chan_lut = np.full((n, n), -1, dtype=np.int64)
-    chan_lut[topo.channels[:, 0], topo.channels[:, 1]] = np.arange(
-        topo.num_channels)
-    for oi, seq in enumerate(seqs):
+    for oi, ids in enumerate(links):
         sel = table.choice == oi
         w = np.where(sel, t, 0.0)
-        hops = seq.shape[-1]
-        for h in range(hops - 1):
-            a, b = seq[..., h], seq[..., h + 1]
-            moving = (a != b) & (chan_lut[a, b] >= 0)
-            if not (a != b).any():
-                break
-            ids = chan_lut[a[moving], b[moving]]
-            np.add.at(load, ids, w[moving])
+        for h in range(ids.shape[-1]):
+            hop = ids[..., h]
+            on = hop >= 0
+            np.add.at(load, hop[on], w[on])
     # a hard-failed (bw == 0) channel carrying planned load is an
     # infinite bottleneck, not a division error
     bw = topo.channel_bw

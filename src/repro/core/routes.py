@@ -24,6 +24,7 @@ __all__ = [
     "route_nodes",
     "route_costs",
     "walk_routes",
+    "route_links",
     "min_rect_contains_channel",
 ]
 
@@ -132,6 +133,27 @@ def walk_routes(topo: Topology, order: tuple[int, ...]) -> np.ndarray:
         cur = nh[cur, dst]
         seq[..., h] = cur
     return seq
+
+
+def route_links(topo: Topology, order: tuple[int, ...]
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """Channel ids along every DOR route, from :func:`walk_routes`.
+
+    Returns ``(links, hops)``: ``links`` (N, N, L) int32 holds the channel
+    id of each hop, −1 past the destination and where the hop has no
+    channel in ``topo`` (a degraded graph); ``hops`` (N, N) is the route
+    length.  A route leaves the graph iff one of its first ``hops``
+    entries is −1.
+    """
+    seq = walk_routes(topo, order)
+    n = topo.num_nodes
+    lut = np.full((n, n), -1, dtype=np.int32)
+    lut[topo.channels[:, 0], topo.channels[:, 1]] = np.arange(
+        topo.num_channels)
+    a, b = seq[..., :-1], seq[..., 1:]
+    moving = a != b
+    links = np.where(moving, lut[a, b], -1)
+    return links, moving.sum(-1)
 
 
 def route_nodes(topo: Topology, s: int, d: int, order: tuple[int, ...]) -> list[int]:

@@ -69,8 +69,12 @@ span                     parent               args
 ``plan_assemble``        ``build_plan_fast``  —
 ``certify``              a gate, ``replan``   ``label``, ``verdict``, CDG
                                               sizes, ``wall_ms``
-``greedy_refine``        ``replan``           ``pairs``, ``sweeps_run``,
-                                              ``changed``
+``greedy_refine``        ``replan``           ``pairs`` (with traffic),
+                                              ``visited`` (those that could
+                                              flip, swept), ``sweeps_run``,
+                                              ``changed``,
+                                              ``route_cache_hit`` (route
+                                              links of the fabric reused)
 ``hot_swap``             ``replan``           ``cycle``, ``shed_pairs``,
                                               ``rejected``: shed guard,
                                               admission control, table retarget

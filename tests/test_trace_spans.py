@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import torus, traffic
+from repro.core.routes import dimension_orders, walk_routes
 from repro.noc import (Algo, LinkFail, LinkRecover, ReplanConfig, Scenario,
                        SimConfig, ctrl, run_controlled)
 from repro.obs.trace import (NULL_TRACER, TraceWriter, read_trace, span,
@@ -105,6 +106,24 @@ def test_greedy_refine_counts_the_pairs_it_sweeps(traced):
         assert ev["args"]["pairs"] == int(want.sum()) > 0
         assert 1 <= ev["args"]["sweeps_run"] <= 2
         assert 0 <= ev["args"]["changed"] <= ev["args"]["pairs"]
+
+
+def test_greedy_refine_visits_only_the_pairs_that_could_flip(traced):
+    _, events, seen = traced
+    refines = [e for e in events if e["name"] == "greedy_refine"]
+    xy, yx = (walk_routes(TOPO, o)
+              for o in dimension_orders(2, binary_only=True))
+    same_route = (xy == yx).all(-1)            # dx == 0 or dy == 0
+    eye = np.eye(TOPO.num_nodes, dtype=bool)
+    for ev, (t, unroutable) in zip(refines, seen):
+        want = (t > 0) & ~eye & ~same_route
+        if unroutable is not None:
+            want &= ~unroutable
+        assert ev["args"]["visited"] == int(want.sum())
+        assert 0 < ev["args"]["visited"] <= ev["args"]["pairs"]
+    # one fabric throughout: every replan after the first finds its
+    # routes cached
+    assert all(e["args"]["route_cache_hit"] is True for e in refines[1:])
 
 
 def test_boundaries_and_epochs_carry_their_counts(traced):
