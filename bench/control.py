@@ -59,7 +59,7 @@ def main(argv=None):
         if mix["service"] == "campaign":
             nums = check.campaign_numbers(grid, config, mix, [out], out,
                                           svc.seed_plan(), seed,
-                                          control=low)
+                                          control=low, chips=len(devs))
         else:
             nums = check.session_numbers(grid, config, mix, out["job"], out,
                                          control=low)

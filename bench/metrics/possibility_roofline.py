@@ -3,6 +3,7 @@ the least time of the passes it ran in the traced window (operations and
 bytes counted from the shapes by ``qsbench.roofline``, against the
 chip's published peaks) over its device time."""
 
+import math
 import os
 import sys
 
@@ -22,6 +23,6 @@ def read(run):
     dev_s = sum(hits.values()) * run.trace["devices"]
     if not calls or dev_s <= 0:
         return None
-    n = run.config["dims"][0] * run.config["dims"][1]
+    n = math.prod(run.config["dims"])
     ops, nbytes = possibility_work(n, n)
     return 100.0 * calls * least_time(ops, nbytes, run.peaks) / dev_s

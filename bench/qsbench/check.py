@@ -92,14 +92,32 @@ def points(job) -> list:
 MAX_LANES = 4
 
 
+def sample_lanes(count: int, chips: int, seed: int) -> list:
+    """Indices of the lanes that the reference simulates again: every
+    lane up to :data:`MAX_LANES`, else that many drawn from ``seed``.
+    Where the lanes divide over the chips, the program shards them in
+    contiguous blocks (chip k holds lanes k·count/chips to
+    (k+1)·count/chips − 1), and the draw takes as many from each block,
+    so that a fault in any one chip's shard is always sampled."""
+    rng = np.random.default_rng([int(seed), 0x1A4E])
+    if count <= MAX_LANES:
+        return list(range(count))
+    if count % chips or MAX_LANES % chips:
+        chips = 1
+    block = count // chips
+    return [k * block + int(i) for k in range(chips)
+            for i in sorted(rng.permutation(block)[:MAX_LANES // chips])]
+
+
 def campaign_numbers(grid, config, mix, outputs, sample, seed_plan,
-                     seed: int, control=None):
+                     seed: int, control=None, chips: int = 1):
     """Numbers of a campaign cell.  ``sample`` is the sampled job's
-    output record, of which at most :data:`MAX_LANES` lanes drawn from
-    ``seed`` are simulated again; ``seed_plan`` is the program's BiDOR
-    table (None for XY).  ``control`` (a lower precision) adds the
-    numbers of the reference computed in it: ``control_argmin_gap`` of
-    its plan and ``control_lanes_differing`` of its simulation."""
+    output record, of which the lanes :func:`sample_lanes` draws from
+    ``seed`` over ``chips`` are simulated again; ``seed_plan`` is the
+    program's BiDOR table (None for XY).  ``control`` (a lower
+    precision) adds the numbers of the reference computed in it:
+    ``control_argmin_gap`` of its plan and ``control_lanes_differing``
+    of its simulation."""
     from .generator import pattern_matrix
     tm = pattern_matrix(grid, mix["pattern"])
     sim = sim_params(config, mix["algo"])
@@ -114,9 +132,7 @@ def campaign_numbers(grid, config, mix, outputs, sample, seed_plan,
             out["control_argmin_gap"] = choice_gap(ref["costs"],
                                                    low["choice"])
     pts = points(sample["job"])
-    rng = np.random.default_rng([int(seed), 0x1A4E])
-    keep = [pts[i] for i in sorted(
-        rng.permutation(len(pts))[:MAX_LANES].tolist())]
+    keep = [pts[i] for i in sample_lanes(len(pts), chips, seed)]
     ref_stats = rsim.run_campaign_lanes(grid, tm, seed_plan, sim, keep,
                                         config["chunk"])
     got = [sample["results"][pts.index(p)] for p in keep

@@ -19,8 +19,9 @@ import numpy as np
 
 def program_topology(config: dict):
     from repro.core import mesh2d, torus
-    w, h = config["dims"]
-    return mesh2d(w, h) if config["fabric"] == "mesh" else torus(w, h)
+    if config["fabric"] == "mesh":
+        return mesh2d(*config["dims"])
+    return torus(*config["dims"])
 
 
 def sim_config(config: dict, algo: str):

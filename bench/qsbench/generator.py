@@ -39,11 +39,11 @@ def pattern_matrix(grid: Grid, name: str) -> np.ndarray:
     if name == "uniform":
         t = np.ones((n, n))
     elif name == "transpose":
-        if grid.dims[0] != grid.dims[1]:
-            raise ValueError("transpose needs a square fabric")
-        c = grid.coords
+        # (x_0, ..., x_{k-1}) -> (x_{k-1}, ..., x_0)
+        if len(set(grid.dims)) != 1:
+            raise ValueError("transpose needs every side equal")
         t = np.zeros((n, n))
-        t[np.arange(n), c[:, 1] + grid.dims[0] * c[:, 0]] = 1.0
+        t[np.arange(n), grid.coords[:, ::-1] @ grid.strides] = 1.0
     else:
         raise ValueError(f"unknown traffic pattern {name!r}")
     np.fill_diagonal(t, 0.0)

@@ -249,8 +249,15 @@ def run_cell(args, *, chips_found=None, bench: str = BENCH,
     if replan_ms:
         say(replan_ms=json.dumps(replan_ms))
 
-    mem = [d.memory_stats() or {} for d in devs]
-    memory_peak = max(int(m.get("peak_bytes_in_use", 0)) for m in mem)
+    # the program places its lanes itself (the campaign service shards
+    # them over every visible chip where they divide), so the chips this
+    # run used are those that held memory, of all that JAX shows
+    visible = jax.devices() if chips_found is None else devs
+    held = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in visible]
+    memory_peak = max(held)
+    used = sum(1 for b in held if b > 0) or len(devs)
+    say(phase="devices", visible=len(visible), used=used, cell_chips=chips)
 
     reduced = None
     if xspace is not None:
@@ -262,6 +269,7 @@ def run_cell(args, *, chips_found=None, bench: str = BENCH,
         say(phase="trace", reduce_s=time.perf_counter() - t_red,
             busy_s=reduced["busy_s"],
             window_s=reduced["window_s"],
+            by_device=json.dumps(reduced["by_device"]),
             modules=json.dumps(sorted(reduced["modules"].items(),
                                       key=lambda kv: -kv[1])[:12]),
             gaps_by_span=json.dumps(reduced["gap_by_span"]),
@@ -278,7 +286,7 @@ def run_cell(args, *, chips_found=None, bench: str = BENCH,
     gc.collect()
     t_cmp = time.perf_counter()
     numbers = compare(check, grid, config, mix, outputs, args.seed,
-                      seed_plan)
+                      seed_plan, chips)
     say(phase="compare", compare_s=time.perf_counter() - t_cmp)
     correct, checks = check.verdict(numbers)
 
@@ -289,7 +297,7 @@ def run_cell(args, *, chips_found=None, bench: str = BENCH,
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     device = {"platform": devs[0].platform, "kind": kind,
-              "count": len(devs), "memory_peak_bytes": memory_peak}
+              "count": used, "memory_peak_bytes": memory_peak}
     result = {"correct": correct, "attempted": len(outputs),
               "failed": check.jobs_failed(outputs), "metrics": metrics,
               "device": device}
@@ -301,14 +309,16 @@ def run_cell(args, *, chips_found=None, bench: str = BENCH,
     return result
 
 
-def compare(check, grid, config, mix, outputs, seed, seed_plan) -> dict:
+def compare(check, grid, config, mix, outputs, seed, seed_plan,
+            chips: int = 1) -> dict:
     """The numbers of the comparison with the plain reference."""
     picked = sample_jobs(outputs, seed, 1)
     if not picked:
         return dict(jobs_failed=1)
     if mix["service"] == "campaign":
         return check.campaign_numbers(grid, config, mix, outputs,
-                                      picked[0], seed_plan, seed)
+                                      picked[0], seed_plan, seed,
+                                      chips=chips)
     out = check.session_numbers(grid, config, mix, picked[0]["job"],
                                 picked[0])
     out.update(jobs_failed=check.jobs_failed(outputs),

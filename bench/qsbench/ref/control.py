@@ -175,7 +175,7 @@ def replay(grid: Grid, traffic, sim: dict, replan_cfg: dict, epoch: int,
 
 
 def unroutable(grid: Grid, channels, bw):
-    """(N, N) pairs that neither XY nor YX can route around the failed
+    """(N, N) pairs that neither order can route around the failed
     links; None on an intact fabric."""
     dead = bw <= 0
     if not dead.any():
@@ -184,8 +184,7 @@ def unroutable(grid: Grid, channels, bw):
     down = np.zeros((n, n), bool)
     down[channels[dead, 0], channels[dead, 1]] = True
     ok_any = np.zeros((n, n), bool)
-    from .grid import ORDERS
-    for o in ORDERS:
+    for o in grid.orders:
         seq = grid.walk(o)
         ok = np.ones((n, n), bool)
         for h in range(seq.shape[-1] - 1):

@@ -1,18 +1,20 @@
 """Plain reference of the fabrics the benchmark runs: 2-D meshes and tori
-with unit-step links, their port numbering, hop distances and
-dimension-order (DOR) routes.
+of any number of dimensions, with unit-step links, their port
+numbering, hop distances and dimension-order (DOR) routes.
 
 Written for the benchmark and importing nothing of the program under
 test.  The numbering follows the simulator's published conventions, so
 that tables built here and there can be compared entry by entry:
 
-* node id = x + W·y (dimension 0 fastest);
+* node id = Σ_k x_k·stride_k with dimension 0 fastest (x + W·y in 2-D,
+  x + W·y + W·H·z in 3-D);
 * channels are the directed links (u, n), sorted lexicographically;
 * output port 2k is the +k direction, 2k+1 the −k direction, and the last
   port (2·ndim) is local inject/eject;
 * on a wrapping dimension the DOR step takes the shorter way round, the
   + way when both are equally short;
-* DOR order 0 is XY (dimension 0 first), order 1 is YX.
+* the DOR orders are the ascending and the descending one: order 0 takes
+  dimension 0 first (XY in 2-D), order 1 the last dimension first (YX).
 """
 
 from __future__ import annotations
@@ -23,12 +25,11 @@ import numpy as np
 
 # hop distance of a pair no path connects (the simulator's convention)
 UNREACHABLE = np.iinfo(np.int32).max // 4
-ORDERS = ((0, 1), (1, 0))
 
 
 @dataclasses.dataclass(frozen=True)
 class Grid:
-    """A 2-D mesh (``wrap`` False) or torus (``wrap`` True)."""
+    """A mesh (``wrap`` False) or torus (``wrap`` True)."""
 
     dims: tuple
     wrap: bool
@@ -51,12 +52,20 @@ class Grid:
 
     @property
     def strides(self) -> np.ndarray:
-        return np.array([1, self.dims[0]], np.int64)
+        return np.cumprod((1,) + self.dims[:-1]).astype(np.int64)
 
     @property
     def coords(self) -> np.ndarray:
-        ids = np.arange(self.n)
-        return np.stack([ids % self.dims[0], ids // self.dims[0]], -1)
+        """(N, ndim) coordinates of every node id."""
+        return np.stack(np.unravel_index(np.arange(self.n), self.dims,
+                                         order="F"), -1).astype(np.int64)
+
+    @property
+    def orders(self) -> tuple:
+        """The DOR orders routes are planned over: ascending and
+        descending dimension order, ((0, 1), (1, 0)) in 2-D."""
+        up = tuple(range(self.ndim))
+        return up, up[::-1]
 
     @property
     def horizon(self) -> int:
@@ -124,7 +133,7 @@ class Grid:
         """(N, N) next node of the DOR route (cur, dst); dst at dst."""
         c = self.coords
         cur, dst = c[:, None, :], c[None, :, :]
-        nxt = np.broadcast_to(cur, (self.n, self.n, 2)).copy()
+        nxt = np.broadcast_to(cur, (self.n, self.n, self.ndim)).copy()
         moved = np.zeros((self.n, self.n), bool)
         for k in order:
             size = self.dims[k]
@@ -166,8 +175,11 @@ class Grid:
 
 
 def make_grid(kind: str, dims) -> Grid:
+    """A 2-D mesh, or a torus of 2 or more dimensions; every side >= 3."""
     if kind not in ("mesh", "torus"):
         raise ValueError(f"unknown fabric {kind!r}")
-    if len(dims) != 2 or min(dims) < 3:
-        raise ValueError(f"a 2-D fabric of sides >= 3 is needed, got {dims}")
+    ndim_ok = len(dims) == 2 if kind == "mesh" else len(dims) >= 2
+    if not ndim_ok or min(dims) < 3:
+        raise ValueError(f"a 2-D mesh or a torus of 2 or more dimensions, "
+                         f"every side >= 3, is needed; got {kind} {dims}")
     return Grid(tuple(int(d) for d in dims), kind == "torus")

@@ -1,5 +1,6 @@
 """Plain reference of the Q-StaR planner: N-Rank (channel-level
-evolution, paper §3.2) → BiDOR's eq. 10 choice between XY and YX →
+evolution, paper §3.2) → BiDOR's eq. 10 choice between the ascending
+and the descending dimension order (XY and YX in 2-D) →
 BiDOR-G's greedy max-link-load refinement.
 
 Written for the benchmark, importing nothing of the program under test.
@@ -22,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .grid import ORDERS, Grid
+from .grid import Grid
 
 W_TH = 0.01      # evolution stops once the channel weight falls below
 ITER_TH = 100    # ... or after this many iterations (paper §3.2.1)
@@ -68,7 +69,7 @@ class Planner:
         self.grid = grid
         self.channels = grid.channels()
         self.c1, self.c2 = consecutive_pairs(self.channels, grid.n)
-        self.nh = np.stack([grid.next_hop(o) for o in ORDERS])
+        self.nh = np.stack([grid.next_hop(o) for o in grid.orders])
         self._dist = {}
 
     def distances(self, live: np.ndarray) -> np.ndarray:
@@ -149,11 +150,11 @@ class Planner:
                 it += 1
             w_final = seg(wc, ns, num_segments=n)
 
-            # eq. 10: cost of every XY and YX route, feasibility on faults
+            # eq. 10: cost of every route of each order, feasibility on faults
             dst = jnp.arange(n)[None, :]
             costs, feas = [], []
-            for o in range(len(ORDERS)):
-                nh = jnp.asarray(self.nh[o])
+            for nh_o in self.nh:
+                nh = jnp.asarray(nh_o)
                 cur = jnp.broadcast_to(jnp.arange(n)[:, None], (n, n))
                 acc = jnp.broadcast_to(w_nr[:, None], (n, n))
                 ok = jnp.ones((n, n), bool)
@@ -218,7 +219,7 @@ class Refiner:
         self.grid = grid
         self.channels = grid.channels()
         n = grid.n
-        self.seqs = [grid.walk(o) for o in ORDERS]
+        self.seqs = [grid.walk(o) for o in grid.orders]
         self.lut = np.full((n, n), -1, np.int64)
         self.lut[self.channels[:, 0], self.channels[:, 1]] = np.arange(
             len(self.channels))
@@ -279,7 +280,7 @@ class Refiner:
                     continue
                 best_oi = cur
                 best_peak = max((load[c] for c in cur_links), default=0.0)
-                for oi in range(len(ORDERS)):
+                for oi in range(len(self.seqs)):
                     if oi == cur:
                         continue
                     alt = links(oi, s, d)

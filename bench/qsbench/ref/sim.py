@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .grid import ORDERS, Grid
+from .grid import Grid
 
 NF = 10
 (F_SRC, F_DST, F_INTER, F_SEQ, F_TIME,
@@ -75,8 +75,8 @@ def build_tables(grid: Grid, traffic, choice=None, *, num_vcs: int,
     cdf, p_gen = gen_tables(traffic, gen_dtype)
     choice = np.zeros((n, n)) if choice is None else choice
     tables = Tables(
-        port=jnp.asarray(np.stack([grid.next_port(o, ch) for o in ORDERS]),
-                         jnp.int32),
+        port=jnp.asarray(np.stack([grid.next_port(o, ch)
+                                   for o in grid.orders]), jnp.int32),
         choice=jnp.asarray(choice, jnp.int32),
         neighbor=jnp.asarray(grid.neighbors(ch), jnp.int32),
         recv_port=jnp.asarray(recv), cdf=cdf, p_gen=p_gen,
@@ -88,7 +88,7 @@ def build_tables(grid: Grid, traffic, choice=None, *, num_vcs: int,
         chan_of=jnp.asarray(chan_of),
         chan_bw=jnp.asarray(np.ones(c) if bw is None else bw, jnp.float32))
     meta = dict(N=n, P=p, V=v, NIN=n * p * v, P_LOCAL=grid.port_local,
-                O=len(ORDERS), C=c)
+                O=len(grid.orders), C=c)
     return tables, meta
 
 

@@ -10,6 +10,8 @@ program's spans on one timeline.
 * per-op and per-module device time — summed durations by op or module
   name, averaged over the devices, and per-op event counts over all
   devices;
+* per device — its busy time and its three longest modules, to show
+  which chips the work reached;
 * idle gaps — the stretches of the window in which no op runs on the
   first device, each put down to the innermost host span that covers
   its middle.
@@ -87,9 +89,10 @@ def reduce(planes, window_unix_ns: float | None = None,
     if not devs:
         raise ValueError("the trace has no device plane")
     busy, ops, modules, counts, first = [], {}, {}, {}, None
+    by_device = []
     names = {}      # an op's event name is its whole HLO instruction
     for p in devs:
-        iv = []
+        iv, mods = [], {}
         for ln in p.lines:
             if ln.name not in (OPS_LINE, MODULES_LINE):
                 continue
@@ -100,6 +103,7 @@ def reduce(planes, window_unix_ns: float | None = None,
                     continue
                 if ln.name == MODULES_LINE:
                     modules[e.name] = modules.get(e.name, 0.0) + (b - a)
+                    mods[e.name] = mods.get(e.name, 0.0) + (b - a)
                     continue
                 name = names.get(e.name)
                 if name is None:
@@ -108,6 +112,9 @@ def reduce(planes, window_unix_ns: float | None = None,
                 iv.append((a, b))
                 counts[name] = counts.get(name, 0) + 1
         busy.append(_union(iv))
+        by_device.append([p.name, busy[-1] / 1e9, {
+            k: v / 1e9 for k, v in sorted(mods.items(),
+                                          key=lambda kv: -kv[1])[:3]}])
         if first is None:
             first = iv
     gaps = []
@@ -131,6 +138,7 @@ def reduce(planes, window_unix_ns: float | None = None,
         ops={k: v / ndev / 1e9 for k, v in ops.items()},
         modules={k: v / ndev / 1e9 for k, v in modules.items()},
         op_counts=counts,
+        by_device=by_device,
         device_ops=[[k, v / ndev / 1e9] for k, v in
                     sorted(ops.items(), key=lambda kv: -kv[1])[:top]],
         idle_gaps=[[k, v] for k, v, _ in gaps[:top]],

@@ -87,44 +87,84 @@ def test_unknown_names_fail():
         harness.load_json("configs", "../BENCHMARK")
 
 
-def test_new_files_only_add_a_cell(tmp_path):
-    """A configuration, a traffic mix, a metric and a cell added as new
-    files and a new workloads entry, with no existing file edited."""
-    bench = tiny_copy(str(tmp_path))
-    with open(os.path.join(bench, "configs", "epiphany-v-mesh32.json")) as f:
+def _add_cell(root: str, base: str, config: dict, mix: dict) -> str:
+    """Add, as new files and new entries, a configuration (``base``'s
+    file with ``config`` laid over it), a traffic mix, a metric and a
+    cell to the tiny copy under ``root``; returns the cell's name."""
+    bench = os.path.join(root, "bench")
+    with open(os.path.join(bench, "configs", base + ".json")) as f:
         cfg = json.load(f)
-    cfg.update(name="mesh5-new", dims=[5, 5])
-    with open(os.path.join(bench, "configs", "mesh5-new.json"), "w") as f:
+    cfg.update(config)
+    name = cfg["name"]
+    with open(os.path.join(bench, "configs", name + ".json"), "w") as f:
         json.dump(cfg, f)
-    with open(os.path.join(bench, "traffic", "xy-transpose-new.json"),
-              "w") as f:
-        json.dump({"service": "campaign", "algo": "XY",
-                   "pattern": "transpose", "rates": [0.05],
-                   "seeds_per_rate": 2}, f)
+    with open(os.path.join(bench, "traffic", name + "-mix.json"), "w") as f:
+        json.dump(mix, f)
     with open(os.path.join(bench, "metrics", "jobs_per_s.py"), "w") as f:
         f.write("def read(run):\n    return len(run.jobs) / run.window_s\n")
-    p = os.path.join(str(tmp_path), "BENCHMARK.json")
+    p = os.path.join(root, "BENCHMARK.json")
     with open(p) as f:
         b = json.load(f)
-    b["configs"].append({"name": "mesh5-new", "source": "test",
-                         "file": "bench/configs/mesh5-new.json",
+    b["configs"].append({"name": name, "source": "test",
+                         "file": f"bench/configs/{name}.json",
                          "reduced": [], "why": "test"})
-    b["workloads"].append({"name": "mesh5-xy-new", "config": "mesh5-new",
-                           "traffic": "xy-transpose-new", "chips": 1,
+    b["workloads"].append({"name": name + "-cell", "config": name,
+                           "traffic": name + "-mix", "chips": 1,
                            "why": "test"})
     b["end_to_end"].append({"name": "jobs_per_s", "unit": "1/s",
                             "better": "higher", "bound": 0.05,
                             "source": "host_clock",
-                            "workloads": ["mesh5-xy-new"]})
+                            "workloads": [name + "-cell"]})
     with open(p, "w") as f:
         json.dump(b, f)
-    res = run_tiny(str(tmp_path), "mesh5-xy-new", seconds=0.5)
+    return name + "-cell"
+
+
+def test_new_files_only_add_a_cell(tmp_path):
+    """A configuration, a traffic mix, a metric and a cell added as new
+    files and a new workloads entry, with no existing file edited."""
+    tiny_copy(str(tmp_path))
+    cell = _add_cell(str(tmp_path), "epiphany-v-mesh32",
+                     dict(name="mesh5-new", dims=[5, 5]),
+                     {"service": "campaign", "algo": "XY",
+                      "pattern": "transpose", "rates": [0.05],
+                      "seeds_per_rate": 2})
+    res = run_tiny(str(tmp_path), cell, seconds=0.5)
     assert res["correct"], res["checks"]
     assert set(res["metrics"]) == {"jobs_per_s", "setup_s"}
 
 
+@pytest.mark.parametrize("service", ["campaign", "control_plane"])
+def test_new_files_only_add_a_3d_torus_cell(tmp_path, service):
+    """A 4x4x4 torus, the shape of a TPU v4 cube, added by new files
+    alone: BiDOR campaign jobs (planner, plan cache, 7-port step) and
+    control-plane sessions under a storm (replans, BiDOR-G and the
+    certifier on three dateline dimensions), each correct against the
+    reference."""
+    tiny_copy(str(tmp_path))
+    if service == "campaign":
+        mix = {"service": "campaign", "algo": "BIDOR",
+               "pattern": "transpose", "rates": [0.05, 0.2],
+               "seeds_per_rate": 1}
+        config = dict(cycles=120, warmup=40, chunk=40)
+    else:
+        with open(os.path.join(str(tmp_path), "bench", "traffic",
+                               "chaos-online.json")) as f:
+            mix = json.load(f)
+        config = {}
+    config.update(name="torus4x4x4-new", dims=[4, 4, 4])
+    cell = _add_cell(str(tmp_path), "tpu-v5e-pod-torus16", config, mix)
+    res = run_tiny(str(tmp_path), cell, seed=2**31 + 17, seconds=0.5)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] >= 1 and res["failed"] == 0
+    assert set(res["metrics"]) == {"jobs_per_s", "setup_s"}
+    if service == "control_plane":
+        assert res["checks"]["schedule_differing"]["value"] == 0
+
+
 @pytest.mark.parametrize("workload", ["mesh32-bidor-transpose",
-                                      "torus16-chaos-online"])
+                                      "torus16-chaos-online",
+                                      "mesh32-xy-uniform-4chip"])
 def test_cell_runs_correct_at_tiny_size(tmp_path, workload):
     tiny_copy(str(tmp_path))
     res = run_tiny(str(tmp_path), workload, seed=2**31 + 11,
@@ -137,6 +177,31 @@ def test_cell_runs_correct_at_tiny_size(tmp_path, workload):
     assert set(res["metrics"]) == want
     assert all(v["value"] > 0 for v in res["metrics"].values())
     assert list(res)[-1] == "checks"
+    assert res["device"]["count"] >= 1
+
+
+@pytest.mark.parametrize("seed", [7, 158735332, 2**31 + 3])
+def test_sampled_lanes_cover_every_chip(seed):
+    """The reference re-runs every lane of a job of up to four; of more,
+    one lane of each chip's contiguous block where the lanes divide over
+    the chips, and the plain draw otherwise."""
+    import numpy as np
+
+    from qsbench.check import MAX_LANES, sample_lanes
+
+    assert sample_lanes(4, 1, seed) == [0, 1, 2, 3]
+    assert sample_lanes(4, 4, seed) == [0, 1, 2, 3]
+    assert sample_lanes(2, 1, seed) == [0, 1]
+    for n in (16, 32):
+        got = sample_lanes(n, 4, seed)
+        assert [i // (n // 4) for i in got] == [0, 1, 2, 3]
+    rng = np.random.default_rng([seed, 0x1A4E])
+    plain = sorted(rng.permutation(16)[:MAX_LANES].tolist())
+    assert sample_lanes(16, 1, seed) == plain
+    assert sample_lanes(18, 4, seed) == sorted(
+        np.random.default_rng([seed, 0x1A4E]).permutation(18)[:4].tolist())
+    seen = {tuple(sample_lanes(16, 4, s)) for s in range(40)}
+    assert len(seen) > 1
 
 
 def test_refuses_without_a_chip(tmp_path):
@@ -219,7 +284,7 @@ def test_traced_run_covers_the_mix_jobs(tmp_path, monkeypatch, capsys,
     def reduce(planes, window_unix_ns=None, host_spans=(), top=10):
         seen["host"] = [p.name for p in planes if p.name.startswith("/host:")]
         return dict(window_s=2.0, busy_s=1.0, devices=1, ops={}, modules={},
-                    op_counts={}, device_ops=[], idle_gaps=[],
+                    op_counts={}, by_device=[], device_ops=[], idle_gaps=[],
                     gap_by_span={}, gaps_at=[], last_op_s=1.9)
 
     monkeypatch.setattr(xtrace, "reduce", reduce)
