@@ -293,8 +293,11 @@ def greedy_refine(topo: Topology, traffic, table: BiDORTable,
     ``stats``, when given, receives the work counts: ``pairs`` (pairs
     with traffic), ``visited`` (those of them each sweep visits: the
     pairs that could flip), ``sweeps_run``, ``changed`` (entries of the
-    output that differ from the input table) and ``route_cache_hit``
-    (the route structure came from the previous call's topology).
+    output that differ from the input table), ``route_cache_hit``
+    (the route structure came from the previous call's topology),
+    ``orders`` (the dimension orders it chooses between) and ``hops``
+    (the route hops of every order, summed over the visited pairs: the
+    links a sweep weighs).
     """
     from .qstar import link_load
 
@@ -391,7 +394,8 @@ def greedy_refine(topo: Topology, traffic, table: BiDORTable,
         stats.update(pairs=pairs, visited=int(idx.size),
                      sweeps_run=sweeps_run,
                      changed=int((choice != table.choice).sum()),
-                     route_cache_hit=hit)
+                     route_cache_hit=hit, orders=len(orders),
+                     hops=int(sum(h.sum() for h in hp)))
     return BiDORTable(choice=choice, orders=orders, costs=table.costs,
                       port_tables=table.port_tables,
                       unroutable=table.unroutable)

@@ -410,7 +410,8 @@ def certify_ports(topo: Topology, port_tables: np.ndarray,
       repair: attempt turn-prohibition repair on a cyclic CDG; False
         certifies only (verdict ``clean`` or ``rejected``).
       tracer: optional tracer (:mod:`repro.obs.trace`); records a
-        ``certify`` span whose args carry ``label`` and the verdict.
+        ``certify`` span whose args carry ``label``, the verdict and
+        ``wrap_dims`` (the dimensions with distinct wrap links).
 
     Returns a :class:`Certificate`.  Raising on rejection is the
     caller's policy (the plan gates raise :class:`CertificationError`).
@@ -420,6 +421,9 @@ def certify_ports(topo: Topology, port_tables: np.ndarray,
         cert = _certify_ports(topo, port_tables, choice, unroutable,
                               traffic, w_nr, repair, max_repair_rounds)
         a.update(cert.trace_args())
+        # the dimensions that carry a dateline layer in the CDG
+        dim, is_wrap = _channel_geometry(topo)
+        a["wrap_dims"] = int(np.unique(dim[is_wrap]).size)
     return cert
 
 

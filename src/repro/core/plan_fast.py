@@ -518,6 +518,7 @@ def build_plan_fast(topo: Topology, traffic: np.ndarray, *,
             down, dist, live, down_pair = _fault_arrays(topo, statics,
                                                         down_channels)
             sa["faults"] = a["faults"] = int(down.size)
+            sa.update(ndim=topo.ndim, orders=len(statics.orders))
         if cache is not None:
             cache.stats.device_builds += 1
         with span(tracer, "plan_device", cat="plan", warm=warm), \

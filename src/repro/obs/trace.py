@@ -63,18 +63,23 @@ span                     parent               args
                                               ``drift_tv``; left out when the
                                               hot-swap guard rejects the plan
 ``build_plan_fast``      ``replan`` or —      ``nodes``, ``warm``, ``faults``
-``plan_statics``         ``build_plan_fast``  ``faults``
+``plan_statics``         ``build_plan_fast``  ``faults``, ``ndim``, ``orders``
 ``plan_device``          ``build_plan_fast``  ``warm``: inputs to the device,
                                               the jitted plan, results back
 ``plan_assemble``        ``build_plan_fast``  —
 ``certify``              a gate, ``replan``   ``label``, ``verdict``, CDG
-                                              sizes, ``wall_ms``
+                                              sizes, ``wall_ms``,
+                                              ``wrap_dims`` (dimensions
+                                              with distinct wrap links)
 ``greedy_refine``        ``replan``           ``pairs`` (with traffic),
                                               ``visited`` (those that could
                                               flip, swept), ``sweeps_run``,
                                               ``changed``,
                                               ``route_cache_hit`` (route
-                                              links of the fabric reused)
+                                              links of the fabric reused),
+                                              ``orders``, ``hops`` (route
+                                              hops of every order over the
+                                              visited pairs)
 ``hot_swap``             ``replan``           ``cycle``, ``shed_pairs``,
                                               ``rejected``: shed guard,
                                               admission control, table retarget

@@ -97,13 +97,12 @@ def test_resolved_simstep_path_compiles(one_chip):
     assert (path == "dense") == ("tpu_custom_call" not in compiled.as_text())
 
 
-def test_planner_compiles_with_the_tpu_defaults(one_chip, monkeypatch):
-    """The device planner as a TPU process builds it — Pallas possibility
-    pass, fp32 — single and batched (the campaign's pattern axis)."""
+def _compile_planner(one_chip, monkeypatch, t, lanes_list):
+    """Compile the device planner of ``t`` as a TPU process builds it —
+    Pallas possibility pass, fp32 — for each lane count."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(plan_fast, "_STATICS_CACHE", {})
     assert plan_fast._use_pallas_default()
-    t = torus(16, 16)
     st = plan_fast.plan_statics(t)
     n, c = st.n, st.c
 
@@ -111,9 +110,21 @@ def test_planner_compiles_with_the_tpu_defaults(one_chip, monkeypatch):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     scalars = (s((), jnp.float32), s((), jnp.int32))
-    for lanes in (1, 2):
+    for lanes in lanes_list:
         args = (s((n, n), jnp.int32), s((lanes, n, n), jnp.float32),
                 s((lanes, n), jnp.float32), s((lanes,), jnp.bool_),
                 s((c,), jnp.bool_), s((n, n), jnp.bool_)) + scalars
         compiled = st.core_batched.lower(*args).compile()
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_planner_compiles_with_the_tpu_defaults(one_chip, monkeypatch):
+    """The device planner as a TPU process builds it, single and batched
+    (the campaign's pattern axis)."""
+    _compile_planner(one_chip, monkeypatch, torus(16, 16), (1, 2))
+
+
+def test_planner_compiles_on_a_3d_torus(one_chip, monkeypatch):
+    """The control plane's planner on a TPU v4 slice's 8x8x8 torus:
+    N = 512, C = 3,072, the XYZ and ZYX orders."""
+    _compile_planner(one_chip, monkeypatch, torus(8, 8, 8), (1,))

@@ -252,3 +252,37 @@ def test_a_rejected_replan_leaves_no_replan_span():
     (swap,) = [a for n, a in col.events if n == "hot_swap"]
     assert swap["rejected"] is True and swap["shed_pairs"] > 0
     assert swap["replan"] == 0
+
+
+@pytest.mark.parametrize("dims", [(4, 4, 4), (6, 6)])
+def test_counters_of_the_fabric_dimensions(dims):
+    """``plan_statics`` counts the fabric's dimensions and BiDOR's
+    orders, ``greedy_refine`` its orders and the route hops it weighs,
+    ``certify`` the dimensions with a dateline layer; on a 3-D and a
+    2-D torus."""
+    from repro.core.certify import certify_table
+    from repro.core.plan_fast import build_plan_fast
+
+    topo = torus(*dims)
+    t = traffic.uniform(topo)
+    col = _Collect()
+    plan = build_plan_fast(topo, t, tracer=col)
+    with span(col, "greedy_refine") as a:
+        ctrl.greedy_refine(topo, t, plan.table, sweeps=2, stats=a)
+    certify_table(topo, plan.table, tracer=col, label="check")
+    args = {}
+    for name, arg in col.events:
+        args.setdefault(name, []).append(arg)
+    (statics,) = args["plan_statics"]
+    assert (statics["ndim"], statics["orders"]) == (len(dims), 2)
+    assert [c["wrap_dims"] for c in args["certify"]] == [len(dims)] * 2
+    # BiDOR-G visits the pairs that differ in two dimensions or more,
+    # and weighs both orders' routes, each as long as the pair's
+    # distance round the rings
+    (refine,) = args["greedy_refine"]
+    delta = np.abs(topo.coords[:, None] - topo.coords[None])
+    ring = np.minimum(delta, np.asarray(dims) - delta).sum(-1)
+    visited = (t > 0) & ((delta != 0).sum(-1) >= 2)
+    assert refine["orders"] == 2
+    assert refine["visited"] == int(visited.sum()) > 0
+    assert refine["hops"] == 2 * int(ring[visited].sum())
